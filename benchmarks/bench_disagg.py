@@ -53,6 +53,8 @@ import time
 
 if "jax" not in sys.modules and "device_count" not in os.environ.get(
         "XLA_FLAGS", ""):
+    # forced host devices are CPU devices: pin the backend to them
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_force_host_platform_device_count=8")
 
@@ -100,7 +102,7 @@ def check_static_ship(arch: str = "smollm-135m", block: int = BLOCK):
     cache = cache.alloc(jnp.arange(rows, dtype=jnp.int32),
                         jnp.full((rows,), max_len, jnp.int32))
     n_cols = kvc.blocks_needed(max_len, block)
-    kvh, hd = cache.k_pool.shape[3], cache.k_pool.shape[4]
+    kvh, hd = cache.k_pool.shape[2], cache.k_pool.shape[4]
     r = jnp.arange(rows, dtype=jnp.int32)
 
     def ship(src, dst):
@@ -108,7 +110,7 @@ def check_static_ship(arch: str = "smollm-135m", block: int = BLOCK):
         return dst.import_rows(r, k, v).k_pool
 
     def densified(src):
-        return (src.export_rows(r, n_cols)[0][0]
+        return (src.export_rows(r, n_cols)[0][0].swapaxes(2, 3)
                 .reshape(rows, n_cols * block, kvh, hd))
 
     out = {

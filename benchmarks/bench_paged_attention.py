@@ -32,6 +32,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jex_core
 import numpy as np
 
 from repro.configs import get_config
@@ -56,17 +57,19 @@ def _collect_shapes(jaxpr, out):
                 out.append((tuple(aval.shape), getattr(aval, "dtype", None)))
         for val in eqn.params.values():
             for leaf in (val if isinstance(val, (tuple, list)) else (val,)):
-                if isinstance(leaf, jax.core.ClosedJaxpr):
+                if isinstance(leaf, jex_core.ClosedJaxpr):
                     _collect_shapes(leaf.jaxpr, out)
-                elif isinstance(leaf, jax.core.Jaxpr):
+                elif isinstance(leaf, jex_core.Jaxpr):
                     _collect_shapes(leaf, out)
 
 
 def dense_kv_intermediates(fn, args, *, rows, max_len, kv, hd):
     """(count, bytes) of dense-layout K/V intermediates in ``fn``'s
     jaxpr: any value shaped ``(rows, T>=max_len, kv, hd)`` (the gather
-    output / its slice) or ``(rows, bpr, block, kv, hd)`` covering
-    >= max_len positions (the pre-reshape gather)."""
+    output / its slice), or a 5-d block gather covering >= max_len
+    positions: ``(rows, bpr, kv, block, hd)`` as read from the
+    head-major pool, ``(rows, bpr, block, kv, hd)`` once swapped for
+    the reshape."""
     shapes = []
     _collect_shapes(jax.make_jaxpr(fn)(*args).jaxpr, shapes)
     n, nbytes = 0, 0
@@ -74,7 +77,9 @@ def dense_kv_intermediates(fn, args, *, rows, max_len, kv, hd):
         hit = (len(s) == 4 and s[0] == rows and s[2] == kv and s[3] == hd
                and s[1] >= max_len) or \
               (len(s) == 5 and s[0] == rows and s[3] == kv and s[4] == hd
-               and s[1] * s[2] >= max_len)
+               and s[1] * s[2] >= max_len) or \
+              (len(s) == 5 and s[0] == rows and s[2] == kv and s[4] == hd
+               and s[1] * s[3] >= max_len)
         if hit:
             n += 1
             nbytes += int(np.prod(s)) * jnp.dtype(dt).itemsize

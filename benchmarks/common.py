@@ -29,10 +29,14 @@ def time_fn(fn, *args, iters: int = 10, warmup: int = 3) -> float:
 def run_multi_device(body: str, n_devices: int = 8, timeout: int = 900) -> str:
     """Run a snippet in a subprocess with N forced host devices.
 
+    The child is pinned to the CPU backend: its devices are forced host
+    devices, and a child that reached for an accelerator would contend
+    with the parent, which holds it once it has touched JAX.
     The snippet should print CSV lines `name,us_per_call,derived`.
     """
     script = textwrap.dedent(f"""
         import os
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n_devices}"
         import sys
         sys.path.insert(0, {os.path.join(REPO, 'src')!r})

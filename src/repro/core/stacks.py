@@ -27,6 +27,7 @@ from typing import Any, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
@@ -36,22 +37,38 @@ DEVICE = "device"
 
 @functools.lru_cache(maxsize=None)
 def host_offload_supported() -> bool:
-    """True if this backend accepts pinned_host placements inside jit."""
-    try:
-        dev = jax.devices()[0]
-        kinds = {m.kind for m in dev.addressable_memories()}
-        if HOST not in kinds:
-            return False
-        h = SingleDeviceSharding(dev, memory_kind=HOST)
-        d = SingleDeviceSharding(dev, memory_kind=DEVICE)
+    """True if this backend accepts pinned_host placements inside jit.
 
-        def f(x):
-            return jax.device_put(jax.device_put(x, h), d) + 1.0
+    On a TPU the answer must be yes: the offload save policies are
+    written for it, and a False there would quietly turn them into
+    device-resident stacks. So on a TPU a missing memory kind, or a
+    round trip that fails, raises instead of returning False.
 
-        jax.jit(f)(jnp.zeros((2,))).block_until_ready()
-        return True
-    except Exception:  # pragma: no cover - backend specific
+    The probe is compiled ahead of time and run on a concrete array, so
+    it gives the same answer when first asked inside a ``jit`` trace
+    (where a plain ``jax.jit(f)(x)`` would only return a tracer)."""
+    dev = jax.devices()[0]
+    on_tpu = dev.platform == "tpu"
+    if HOST not in {m.kind for m in dev.addressable_memories()}:
+        if on_tpu:
+            raise RuntimeError(f"TPU device {dev} exposes no {HOST!r} "
+                               f"memory: host offload cannot run")
         return False
+    h = SingleDeviceSharding(dev, memory_kind=HOST)
+    d = SingleDeviceSharding(dev, memory_kind=DEVICE)
+
+    def f(x):
+        return jax.device_put(jax.device_put(x, h), d) + 1.0
+
+    try:
+        probe = jax.jit(f).lower(
+            jax.ShapeDtypeStruct((2,), jnp.float32)).compile()
+        probe(np.zeros((2,), np.float32)).block_until_ready()
+    except jax.errors.JaxRuntimeError:
+        if on_tpu:
+            raise
+        return False
+    return True
 
 
 def _single_dev(kind: str):

@@ -74,14 +74,14 @@ def _is_inexact_leaf(x) -> bool:
 
 
 def _float0_zero(x):
-    aval = jax.core.get_aval(x)
+    aval = jax.typeof(x)
     return np.zeros(aval.shape, jax.dtypes.float0)
 
 
 def _zero_ct(x):
     """Zero cotangent per custom_vjp conventions (float0 for ints/bools)."""
     if _is_inexact_leaf(x):
-        aval = jax.core.get_aval(x)
+        aval = jax.typeof(x)
         return jnp.zeros(aval.shape, aval.dtype)
     return _float0_zero(x)
 

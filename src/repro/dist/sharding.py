@@ -258,10 +258,9 @@ def slice_mesh(devices, axes=("data",), shape=None) -> Mesh:
 
     ``jax.make_mesh`` always spans the whole fleet; a slice mesh must
     not, so this goes through ``Mesh`` directly with the devices
-    reshaped to ``shape`` (default: 1-D over a single axis). The
-    AxisType guard mirrors ``launch.mesh._mesh``: jax < 0.5 has no
-    axis_types (everything is Auto there); newer versions need Auto
-    spelled out to keep GSPMD auto-propagation on the slice.
+    reshaped to ``shape`` (default: 1-D over a single axis). Axes are
+    Auto, as in ``launch.mesh._mesh``, to keep GSPMD auto-propagation
+    on the slice.
     """
     import numpy as _np
     devices = list(devices)
@@ -276,18 +275,16 @@ def slice_mesh(devices, axes=("data",), shape=None) -> Mesh:
         raise ValueError(
             f"shape {shape} wants {n} devices, got {len(devices)}")
     arr = _np.array(devices, dtype=object).reshape(shape)
-    if hasattr(jax.sharding, "AxisType"):
-        return Mesh(arr, axes,
-                    axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return Mesh(arr, axes)
+    return Mesh(arr, axes,
+                axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def transfer_sharding(rules: ShardingRules, mesh: Mesh,
                       dims) -> NamedSharding:
     """Destination sharding for a shipped KV-block buffer.
 
-    The wire format is ``(L, R, n_cols, block, KV, hd)`` — layers,
-    shipped rows, table columns, block, kv heads, head dim
+    The wire format is ``(L, R, n_cols, KV, block, hd)`` — layers,
+    shipped rows, table columns, kv heads, block, head dim
     (``PagedKVCache.export_rows``). Placement matches the DESTINATION
     pool's K/V pools on the head axes (``CACHE_KV``/``CACHE_HD`` — cut
     when the slice mesh has a model axis) and keeps the tiny row/column
@@ -296,7 +293,7 @@ def transfer_sharding(rules: ShardingRules, mesh: Mesh,
     the decode slice. On a data-only slice mesh every axis drops
     (divisibility) and the buffer is simply replicated over the slice.
     """
-    return rules.sharding((LAYERS, None, None, None, CACHE_KV, CACHE_HD),
+    return rules.sharding((LAYERS, None, None, CACHE_KV, None, CACHE_HD),
                           mesh, dims=tuple(dims))
 
 

@@ -6,6 +6,14 @@ innermost (sequential) grid axis so XLA streams K/V blocks HBM→VMEM
 while the MXU consumes the previous block. Causal skipping is done with
 ``pl.when`` on whole blocks above the diagonal.
 
+Operands are head-major, ``(B, H, S, D)``: a grid step's block is
+``(1, 1, blk, D)``, so its last two dims are the array's own
+``(S, D)`` tiles — the TPU compiler requires each of the last two block
+dims to be a multiple of (8, 128) or the whole array dim, which a
+``(blk, 1, D)`` slice of a token-major ``(B, S, H, D)`` array fails on
+the head axis. ``ops.flash_attention`` takes the model's token-major
+layout and transposes.
+
 Block shapes default to (128, 128): the MXU is 128×128, so q/k tiles are
 hardware-aligned; VMEM footprint per step is
 q(128·D) + k(128·D) + v(128·D) + acc(128·D) + stats ≈ 4·128·D·4B ≈ 256KB
@@ -39,9 +47,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale      # (blk_q, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)              # (blk_k, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32) * scale            # (blk_q, D)
+        k = k_ref[0, 0].astype(jnp.float32)                    # (blk_k, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (bq, bk)
         if causal:
             qpos = qi * blk_q + jax.lax.broadcasted_iota(
@@ -68,14 +76,14 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     @pl.when(j == nk - 1)
     def _finalize():
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, blk_q: int = 128,
                     blk_k: int = 128, interpret: bool = True):
-    """q: (B, S, H, D); k/v: (B, T, KV, D) -> (B, S, H, D)."""
-    B, S, H, D = q.shape
-    T, KV = k.shape[1], k.shape[2]
+    """Head-major: q: (B, H, S, D); k/v: (B, KV, T, D) -> (B, H, S, D)."""
+    B, H, S, D = q.shape
+    KV, T = k.shape[1], k.shape[2]
     G = H // KV
     blk_q = min(blk_q, S)
     blk_k = min(blk_k, T)
@@ -90,14 +98,14 @@ def flash_attention(q, k, v, *, causal: bool = True, blk_q: int = 128,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, blk_q, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, blk_k, 1, D),
-                         lambda b, h, i, j, G=G: (b, j, h // G, 0)),
-            pl.BlockSpec((1, blk_k, 1, D),
-                         lambda b, h, i, j, G=G: (b, j, h // G, 0)),
+            pl.BlockSpec((1, 1, blk_q, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, blk_k, D),
+                         lambda b, h, i, j, G=G: (b, h // G, j, 0)),
+            pl.BlockSpec((1, 1, blk_k, D),
+                         lambda b, h, i, j, G=G: (b, h // G, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, blk_q, 1, D),
-                               lambda b, h, i, j: (b, i, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, blk_q, D),
+                               lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((blk_q, D), jnp.float32),

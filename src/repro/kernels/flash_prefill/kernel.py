@@ -13,9 +13,11 @@ runs, so the BlockSpec index maps chase the indirection: grid step
 Layout/behaviour contract (shared with ``ref.py`` and
 ``serve.kv_cache.PagedView``):
 
-- pools are ``(n_blocks, block, KV, hd)`` — one layer's slice of the
-  cache's ``(L, n_blocks, ...)`` pool; the chunk's OWN K/V must be in
-  the pool before the call (``PagedView.write_chunk`` first);
+- pools are head-major ``(n_blocks, KV, block, hd)`` — one layer's
+  slice of the cache's ``(L, n_blocks, ...)`` pool, read as
+  ``(1, 1, block, hd)`` blocks (the TPU compiler's tiling rule, see
+  ``kernels.paged_attention``); the chunk's OWN K/V must be in the
+  pool before the call (``PagedView.write_chunk`` first);
 - causal: query ``i`` of row ``b`` sees lanes ``[0, q_off[b] + i]``
   and nothing else — ragged-tail/garbage lanes beyond the last real
   query are only ever visible to garbage queries the caller discards;
@@ -64,8 +66,8 @@ def _fp_kernel(table_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
 
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale        # (R, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (block, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                # (block, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         qpos = off + jax.lax.broadcasted_iota(jnp.int32, (R, block), 0) // G
@@ -93,10 +95,10 @@ def _fp_kernel(table_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
 
 def flash_prefill(q, k_pool, v_pool, table, q_off, *,
                   interpret: bool = True):
-    """q: (B, C, H, hd); k/v_pool: (n_blocks, block, KV, hd);
+    """q: (B, C, H, hd); k/v_pool: (n_blocks, KV, block, hd);
     table: (B, bpr) int32; q_off: (B,) int32 -> (B, C, H, hd)."""
     B, C, H, hd = q.shape
-    block, KV = k_pool.shape[1], k_pool.shape[2]
+    KV, block = k_pool.shape[1], k_pool.shape[2]
     G = H // KV
     bpr = table.shape[1]
     scale = 1.0 / math.sqrt(hd)
@@ -112,7 +114,7 @@ def flash_prefill(q, k_pool, v_pool, table, q_off, *,
         # pipeline sees an unchanged block index and skips the DMA.
         last = jnp.maximum((off_ref[b] + C - 1) // block, 0)
         jj = jnp.minimum(j, last)
-        return (jnp.maximum(table_ref[b, jj], 0), 0, h, 0)
+        return (jnp.maximum(table_ref[b, jj], 0), h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -120,8 +122,8 @@ def flash_prefill(q, k_pool, v_pool, table, q_off, *,
         in_specs=[
             pl.BlockSpec((1, 1, C * G, hd),
                          lambda b, h, j, t, c: (b, h, 0, 0)),
-            pl.BlockSpec((1, block, 1, hd), kv_map),
-            pl.BlockSpec((1, block, 1, hd), kv_map),
+            pl.BlockSpec((1, 1, block, hd), kv_map),
+            pl.BlockSpec((1, 1, block, hd), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, C * G, hd),
                                lambda b, h, j, t, c: (b, h, 0, 0)),

@@ -2,10 +2,12 @@
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
-from .. import on_tpu
+from .. import on_tpu, per_device
 from .kernel import flash_prefill as _kernel
 from .ref import flash_prefill_ref
 
@@ -13,8 +15,9 @@ from .ref import flash_prefill_ref
 @jax.jit
 def flash_prefill(q, k_pool, v_pool, table, q_off):
     """Dispatch: compiled Pallas on TPU, interpret-mode elsewhere."""
-    return _kernel(q, k_pool, v_pool, table, q_off,
-                   interpret=not on_tpu())
+    kern = functools.partial(_kernel, interpret=not on_tpu())
+    return per_device(kern, rows=(0, 3, 4))(q, k_pool, v_pool, table,
+                                            q_off)
 
 
 @jax.jit
@@ -34,14 +37,14 @@ def flash_verify(q, k_pool, v_pool, table, q_off):
     independent, so real rows are untouched.
     """
     B, W, hq, hd = q.shape
-    g = hq // k_pool.shape[2]
+    g = hq // k_pool.shape[1]
     wp = W
     while (wp * g) % 8:
         wp += 1
     if wp != W:
         q = jnp.pad(q, ((0, 0), (0, wp - W), (0, 0), (0, 0)))
-    out = _kernel(q, k_pool, v_pool, table, q_off,
-                  interpret=not on_tpu())
+    kern = functools.partial(_kernel, interpret=not on_tpu())
+    out = per_device(kern, rows=(0, 3, 4))(q, k_pool, v_pool, table, q_off)
     return out[:, :W]
 
 

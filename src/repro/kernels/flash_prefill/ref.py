@@ -24,16 +24,16 @@ NEG_INF = -1e30
 
 
 def flash_prefill_ref(q, k_pool, v_pool, table, q_off):
-    """q: (B, C, H, hd); pools: (n_blocks, block, KV, hd);
+    """q: (B, C, H, hd); pools: (n_blocks, KV, block, hd);
     table: (B, bpr) int32 (-1 = unallocated); q_off: (B,) int32.
     Returns (B, C, H, hd). fp32 math."""
     B, C, H, hd = q.shape
-    block, KV = k_pool.shape[1], k_pool.shape[2]
+    KV, block = k_pool.shape[1], k_pool.shape[2]
     G = H // KV
     bpr = table.shape[1]
     safe = jnp.clip(table, 0)
-    kg = k_pool[safe].reshape(B, bpr * block, KV, hd)
-    vg = v_pool[safe].reshape(B, bpr * block, KV, hd)
+    kg = k_pool[safe].swapaxes(2, 3).reshape(B, bpr * block, KV, hd)
+    vg = v_pool[safe].swapaxes(2, 3).reshape(B, bpr * block, KV, hd)
     T = kg.shape[1]
     qf = (q.astype(jnp.float32) / math.sqrt(hd)).reshape(B, C, KV, G, hd)
     s = jnp.einsum("bckgd,btkd->bkgct", qf, kg.astype(jnp.float32),

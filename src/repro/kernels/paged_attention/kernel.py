@@ -18,8 +18,13 @@ inside the compiled step.
 Layout/behaviour contract (shared with ``ref.py`` and
 ``serve.kv_cache.PagedView``):
 
-- pools are ``(n_blocks, block, KV, hd)`` — one layer's slice of the
-  cache's ``(L, n_blocks, ...)`` pool;
+- pools are head-major ``(n_blocks, KV, block, hd)`` — one layer's
+  slice of the cache's ``(L, n_blocks, ...)`` pool. A grid step's K/V
+  block is ``(1, 1, block, hd)``: its last two dims are the pool's own
+  ``(block, hd)``, which the TPU compiler requires (each of the last
+  two block dims must be a multiple of (8, 128) or the whole array
+  dim; a token-major ``(block, 1, hd)`` slice fails that on the head
+  axis);
 - ``table`` entries < 0 (unallocated) clip to physical block 0 and the
   garbage is masked by ``cur_len`` — same lanes the gather path masks;
 - blocks at or beyond ``ceil(cur_len/block)`` are clamped to the last
@@ -72,8 +77,8 @@ def _pa_kernel(table_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
 
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale        # (G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (block, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                # (block, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         pos = j * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
@@ -100,10 +105,10 @@ def _pa_kernel(table_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_attention(q, k_pool, v_pool, table, cur_len, *,
                     interpret: bool = True):
-    """q: (B, 1, H, hd); k/v_pool: (n_blocks, block, KV, hd);
+    """q: (B, 1, H, hd); k/v_pool: (n_blocks, KV, block, hd);
     table: (B, bpr) int32; cur_len: (B,) int32 -> (B, 1, H, hd)."""
     B, _, H, hd = q.shape
-    block, KV = k_pool.shape[1], k_pool.shape[2]
+    KV, block = k_pool.shape[1], k_pool.shape[2]
     G = H // KV
     bpr = table.shape[1]
     scale = 1.0 / math.sqrt(hd)
@@ -122,7 +127,7 @@ def paged_attention(q, k_pool, v_pool, table, cur_len, *,
         # sees an unchanged block index and skips the DMA entirely.
         last = jnp.maximum((cl_ref[b] + block - 1) // block - 1, 0)
         jj = jnp.minimum(j, last)
-        return (jnp.maximum(table_ref[b, jj], 0), 0, h, 0)
+        return (jnp.maximum(table_ref[b, jj], 0), h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -130,8 +135,8 @@ def paged_attention(q, k_pool, v_pool, table, cur_len, *,
         in_specs=[
             pl.BlockSpec((1, 1, Gt, hd),
                          lambda b, h, g, j, t, c: (b, h, g, 0)),
-            pl.BlockSpec((1, block, 1, hd), kv_map),
-            pl.BlockSpec((1, block, 1, hd), kv_map),
+            pl.BlockSpec((1, 1, block, hd), kv_map),
+            pl.BlockSpec((1, 1, block, hd), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, Gt, hd),
                                lambda b, h, g, j, t, c: (b, h, g, 0)),

@@ -25,20 +25,20 @@ def gather_kv(k_pool, v_pool, table):
     garbage they read is masked by ``cur_len`` downstream, mirroring
     ``PagedView.gather``.
     """
-    n_blocks, block, KV, hd = k_pool.shape
+    n_blocks, KV, block, hd = k_pool.shape
     B, bpr = table.shape
     safe = jnp.clip(table, 0)
-    kg = k_pool[safe].reshape(B, bpr * block, KV, hd)
-    vg = v_pool[safe].reshape(B, bpr * block, KV, hd)
+    kg = k_pool[safe].swapaxes(2, 3).reshape(B, bpr * block, KV, hd)
+    vg = v_pool[safe].swapaxes(2, 3).reshape(B, bpr * block, KV, hd)
     return kg, vg
 
 
 def paged_attention_ref(q, k_pool, v_pool, table, cur_len):
-    """q: (B, 1, H, hd); pools: (n_blocks, block, KV, hd);
+    """q: (B, 1, H, hd); pools: (n_blocks, KV, block, hd);
     table: (B, bpr) int32 (-1 = unallocated); cur_len: (B,) int32.
     Returns (B, 1, H, hd). fp32 math."""
     B, _, H, hd = q.shape
-    KV = k_pool.shape[2]
+    KV = k_pool.shape[1]
     G = H // KV
     kg, vg = gather_kv(k_pool, v_pool, table)
     T = kg.shape[1]

@@ -175,8 +175,6 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool):
 
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, list):  # jax < 0.5 returns one dict per device
-        ca = ca[0] if ca else {}
     hlo_text = compiled.as_text()
     cost = hlo_lib.analyze(hlo_text)
 

@@ -11,13 +11,10 @@ import jax
 
 
 def _mesh(shape, axes):
-    # jax < 0.5 has no jax.sharding.AxisType (everything is Auto there);
-    # newer versions need it spelled out to keep GSPMD auto-propagation.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    # Auto axes keep GSPMD auto-propagation (make_mesh defaults to
+    # Explicit).
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -91,6 +91,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.dist import sharding as sh
 from repro.launch import distributed as dist_env
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_zoo
 from repro.serve import disagg as disagg_lib
 from repro.serve import engine, sampling
@@ -120,6 +121,19 @@ def build_workload(args, rng):
     return rows
 
 
+def build_prompts(args, cfg, n: int):
+    """Request id -> (1, prompt_len) int32 prompt for ``n`` requests,
+    from ``args.seed``. ``--prompt-pool P`` draws them from P distinct
+    prompts (default: all distinct) — hot repeated prompts are the
+    traffic ``--prefix-cache`` exists for."""
+    rng = np.random.default_rng(args.seed)
+    pool_n = args.prompt_pool or n
+    pool = [rng.integers(2, cfg.vocab,
+                         (1, args.prompt_len)).astype(np.int32)
+            for _ in range(pool_n)]
+    return {i: pool[i % pool_n] for i in range(n)}
+
+
 def pctl(xs, q):
     return float(np.percentile(np.asarray(xs), q)) if len(xs) else 0.0
 
@@ -146,21 +160,16 @@ def run_continuous(args, cfg, params, workload):
         prefill=args.prefill, chunk_tokens=args.chunk_tokens,
         prefix_cache=args.prefix_cache, speculative=spec,
         draft_params=draft_params, draft_cfg=draft_cfg)
-    rng = np.random.default_rng(args.seed)
-    # --prompt-pool P draws the workload's prompts from P distinct
-    # prompts (default: all distinct) — hot repeated prompts are the
-    # traffic --prefix-cache exists for
-    pool_n = args.prompt_pool or len(workload)
-    pool = [rng.integers(2, cfg.vocab,
-                         (1, args.prompt_len)).astype(np.int32)
-            for _ in range(pool_n)]
-    prompts = {i: pool[i % pool_n] for i in range(len(workload))}
+    prompts = build_prompts(args, cfg, len(workload))
     # Warm compiles outside the timed window (prefill + both step modes).
+    t_warm = time.perf_counter()
     sched.warmup()
+    compile_s = time.perf_counter() - t_warm
 
     arrival_wall = {}
     finish_wall = {}
     req_depth = {}
+    req_tokens = {}
     t0 = time.perf_counter()
     next_req = 0
     idle_s = 0.0          # open-loop arrival gaps: excluded from tok/s
@@ -184,6 +193,7 @@ def run_continuous(args, cfg, params, workload):
         for f in sched.step(expect_arrivals=next_req < len(workload)):
             finish_wall[f.request_id] = time.perf_counter() - t0
             req_depth[f.request_id] = f.mean_depth
+            req_tokens[f.request_id] = f.tokens
     wall = time.perf_counter() - t0
     busy = max(wall - idle_s, 1e-9)
     lat = [finish_wall[r] - arrival_wall[r] for r in finish_wall]
@@ -201,7 +211,8 @@ def run_continuous(args, cfg, params, workload):
             "accept_rate": sched.accept_rate,
             "mean_accept_len": sched.mean_accept_len,
             "mean_depth": sched.mean_depth,
-            "req_depth": req_depth}
+            "req_depth": req_depth, "req_tokens": req_tokens,
+            "compile_s": compile_s}
 
 
 def run_disagg(args, cfg, params, workload):
@@ -245,16 +256,14 @@ def run_disagg(args, cfg, params, workload):
         chunk_tokens=args.chunk_tokens,
         prefix_cache=args.prefix_cache, speculative=spec,
         segment_steps=args.segment_steps)
-    rng = np.random.default_rng(args.seed)
-    pool_n = args.prompt_pool or len(workload)
-    pool = [rng.integers(2, cfg.vocab,
-                         (1, args.prompt_len)).astype(np.int32)
-            for _ in range(pool_n)]
-    prompts = {i: pool[i % pool_n] for i in range(len(workload))}
+    prompts = build_prompts(args, cfg, len(workload))
+    t_warm = time.perf_counter()
     sched.warmup()
+    compile_s = time.perf_counter() - t_warm
 
     arrival_wall = {}
     finish_wall = {}
+    req_tokens = {}
     t0 = time.perf_counter()
     next_req = 0
     idle_s = 0.0
@@ -274,7 +283,13 @@ def run_disagg(args, cfg, params, workload):
             continue
         for f in sched.step(expect_arrivals=next_req < len(workload)):
             finish_wall[f.request_id] = time.perf_counter() - t0
+            req_tokens[f.request_id] = f.tokens
     wall = time.perf_counter() - t0
+    kv_key = engine.kv_key(cfg)
+
+    def pool_devices(tier):
+        return sorted(d.id for d in tier.pool.cache[kv_key].k_pool.devices())
+
     busy = max(wall - idle_s, 1e-9)
     lat = [finish_wall[r] - arrival_wall[r] for r in finish_wall]
     toks = sched.tokens_emitted
@@ -290,7 +305,10 @@ def run_disagg(args, cfg, params, workload):
             "preemptions": sched.preemptions,
             "replay_mismatches": sched.replay_mismatches,
             "prefill_devices": len(pf_mesh.devices.flat) if pf_mesh else 1,
-            "decode_devices": len(de_mesh.devices.flat) if de_mesh else 1}
+            "decode_devices": len(de_mesh.devices.flat) if de_mesh else 1,
+            "prefill_pool_devices": pool_devices(sched.prefill),
+            "decode_pool_devices": pool_devices(sched.decode),
+            "req_tokens": req_tokens, "compile_s": compile_s}
 
 
 def run_stream(args, cfg, params, workload):
@@ -316,18 +334,14 @@ def run_stream(args, cfg, params, workload):
     sched.warmup()
     slo = slo_lib.SLOScheduler(sched, segment_steps=args.segment_steps)
     front = fe.StreamingFrontend(slo, max_inflight=args.max_inflight)
-    rng = np.random.default_rng(args.seed)
-    pool_n = args.prompt_pool or len(workload)
-    pool = [rng.integers(2, cfg.vocab,
-                         (1, args.prompt_len)).astype(np.int32)
-            for _ in range(pool_n)]
+    prompts = build_prompts(args, cfg, len(workload))
 
     async def client(i, arrival, max_new):
         await asyncio.sleep(arrival)
         klass = ("interactive" if args.hi_every
                  and i % args.hi_every == 0 else "batch")
         toks = 0
-        async for ev in front.stream(pool[i % pool_n], max_new=max_new,
+        async for ev in front.stream(prompts[i], max_new=max_new,
                                      slo_class=klass, request_id=i):
             if ev["event"] == "token":
                 toks += len(ev["tokens"])
@@ -392,7 +406,7 @@ def run_batch_sync(args, cfg, params, workload):
             "attn_impl": attn_impl}
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -524,8 +538,12 @@ def main():
                     help="--stream: every Nth request is the "
                          "'interactive' (preempting) class; 0 = all "
                          "batch")
-    args = ap.parse_args()
+    return ap
 
+
+def model_config(args):
+    """The served ``ModelConfig``: the arch's config with the
+    attention-path and adaptive-depth options applied."""
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.attn_impl is not None:
         cfg = dataclasses.replace(cfg, attn_impl=args.attn_impl)
@@ -535,6 +553,13 @@ def main():
             exit_threshold=args.exit_threshold,
             exit_min_layers=args.exit_min_layers,
             mod_capacity=args.mod_capacity, mod_every=args.mod_every)
+    return cfg
+
+
+def main():
+    enable_compile_cache()
+    args = build_parser().parse_args()
+    cfg = model_config(args)
     params = model_zoo.init_params(cfg, jax.random.PRNGKey(0))
     workload = build_workload(args, np.random.default_rng(args.seed))
 
@@ -591,7 +616,8 @@ def main():
           f"latency p50 {cont['p50_s'] * 1e3:.0f}ms "
           f"p99 {cont['p99_s'] * 1e3:.0f}ms | "
           f"occupancy {cont['occupancy'] * 100:.0f}% "
-          f"({cont['steps']} device steps)")
+          f"({cont['steps']} device steps) | "
+          f"warmup compile {cont['compile_s']:.2f}s")
     if args.early_exit:
         # per-class mean layers/token: group requests by their
         # max_new budget (the workload's short/long classes)

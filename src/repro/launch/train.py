@@ -16,26 +16,40 @@ overlap — DESIGN.md §9); they are no-ops on CPU.
 import argparse
 import os
 
-# compute/communication overlap: enable XLA's latency-hiding scheduler
-# and async collectives on the TPU target (harmless on CPU).
-os.environ.setdefault(
-    "LIBTPU_INIT_ARGS",
-    "--xla_enable_async_all_gather=true "
-    "--xla_enable_async_collective_permute=true "
-    "--xla_tpu_enable_data_parallel_all_reduce_opt=true")
-
 import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.data.pipeline import Prefetcher, SyntheticLM
 from repro.dist.sharding import logical_to_sharding
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_zoo
 from repro.optim import adamw, schedule
 from repro.train import train_loop
 
+# compute/communication overlap: XLA's latency-hiding scheduler and
+# async collectives on the TPU target (harmless on CPU).
+TPU_FLAGS = ("--xla_enable_async_all_gather=true",
+             "--xla_enable_async_collective_permute=true",
+             "--xla_tpu_enable_data_parallel_all_reduce_opt=true")
+
+
+def append_tpu_flags() -> str:
+    """Append ``TPU_FLAGS`` to ``LIBTPU_INIT_ARGS``; return the result.
+
+    The TPU host may already hold flags there (some work round compiler
+    faults), so nothing it set is dropped, and a flag it already names
+    keeps its value. Must run before the TPU backend initializes."""
+    have = os.environ.get("LIBTPU_INIT_ARGS", "").split()
+    names = {f.split("=", 1)[0] for f in have}
+    add = [f for f in TPU_FLAGS if f.split("=", 1)[0] not in names]
+    os.environ["LIBTPU_INIT_ARGS"] = " ".join(have + add)
+    return os.environ["LIBTPU_INIT_ARGS"]
+
 
 def main():
+    append_tpu_flags()
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",

@@ -36,9 +36,14 @@ from . import layers, moe as moe_lib, ssm as ssm_lib
 def attn_params(b, cfg, d_model: int):
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     p = {
-        "wq": b.p((d_model, H, hd), (sh.EMBED, sh.HEADS, sh.HEAD_DIM)),
-        "wk": b.p((d_model, KV, hd), (sh.EMBED, sh.KV_HEADS, sh.HEAD_DIM)),
-        "wv": b.p((d_model, KV, hd), (sh.EMBED, sh.KV_HEADS, sh.HEAD_DIM)),
+        # fan-in is d_model: the default (second-to-last dim) would
+        # take the head count and make random attention one-hot
+        "wq": b.p((d_model, H, hd), (sh.EMBED, sh.HEADS, sh.HEAD_DIM),
+                  fan_in=d_model),
+        "wk": b.p((d_model, KV, hd), (sh.EMBED, sh.KV_HEADS, sh.HEAD_DIM),
+                  fan_in=d_model),
+        "wv": b.p((d_model, KV, hd), (sh.EMBED, sh.KV_HEADS, sh.HEAD_DIM),
+                  fan_in=d_model),
         "wo": b.p((H, hd, d_model), (sh.HEADS, sh.HEAD_DIM, sh.EMBED),
                   fan_in=H * hd),
     }

@@ -24,7 +24,7 @@ onto **disjoint submeshes** of one device fleet:
 
 Between them, finished KV blocks ship slice-to-slice asynchronously:
 ``export_rows`` gathers the row's blocks into a fresh wire buffer
-``(L, 1, n_cols, block, KV, hd)`` on the prefill slice,
+``(L, 1, n_cols, KV, block, hd)`` on the prefill slice,
 ``jax.device_put`` dispatches the transfer into the decode pool's
 sharding (``dist.sharding.transfer_sharding``) without blocking the
 host, and the shipment rides an in-transit queue for one full round
@@ -108,13 +108,8 @@ def _replicate(params, mesh):
 def _segment_done(arr) -> bool:
     """Non-blocking readiness poll of a dispatched segment's result.
 
-    ``jax.Array.is_ready`` answers without synchronizing; a runtime
-    without it degrades to blocking — correct, just without the
-    cross-round overlap the poll buys."""
-    try:
-        return bool(arr.is_ready())
-    except AttributeError:
-        return True
+    ``jax.Array.is_ready`` answers without synchronizing."""
+    return bool(arr.is_ready())
 
 
 @dataclasses.dataclass
@@ -124,7 +119,7 @@ class _Shipment:
     req: sched_lib._Queued   # original record (real max_new restored)
     t0: int                  # first token, sampled on the prefill slice
     plen: int                # prefilled stream length (prompt + prefix)
-    k: Any                   # (L, 1, n_cols, block, KV, hd) wire buffers,
+    k: Any                   # (L, 1, n_cols, KV, block, hd) wire buffers,
     v: Any                   # device_put toward the decode slice
     round: int               # dispatch round — spliced strictly later
 
@@ -251,7 +246,7 @@ class DisaggScheduler:
 
         def export(pool, rows):
             """Gather one harvested row's leading blocks into a fresh
-            (L, 1, n_cols, block, KV, hd) wire buffer. Fresh matters:
+            (L, 1, n_cols, KV, block, hd) wire buffer. Fresh matters:
             the buffer aliases nothing in the pool, so the prefill tier
             may recycle the row's blocks (``release_slots``) while the
             device_put of this buffer is still in flight."""

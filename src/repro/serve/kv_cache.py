@@ -11,8 +11,8 @@ protocol with two implementations (DESIGN.md §8):
   ``engine.make_cache`` tuple plumbing. Zero indirection; memory is
   ``n_rows × max_len`` regardless of occupancy.
 - ``PagedKVCache`` — vLLM-style block tables: fixed-size blocks in a
-  shared pool ``(L, n_blocks, block, KV, hd)``, a per-row block table
-  ``(n_rows, blocks_per_row)`` and an in-graph free-list (the
+  shared head-major pool ``(L, n_blocks, KV, block, hd)``, a per-row
+  block table ``(n_rows, blocks_per_row)`` and an in-graph free-list (the
   ``refcount`` vector — a block is free iff ``refcount == 0``).
   ``alloc``/``free`` are pure array ops, so admission and retirement
   stay inside the runtime: a retired slot's blocks are reusable by the
@@ -75,6 +75,26 @@ def blocks_needed(n_tokens, block: int):
 def _bcast_rows(rows: Optional[jax.Array], n: int) -> jax.Array:
     return jnp.arange(n, dtype=jnp.int32) if rows is None \
         else jnp.asarray(rows, jnp.int32)
+
+
+def set_rows(vec: jax.Array, rows, new: jax.Array, mask) -> jax.Array:
+    """``vec`` with row ``rows[i]`` set to ``new[i]`` where ``mask[i]``
+    (masked rows distinct; out-of-range rows write nothing).
+
+    A select over ``vec``'s rows, each picking its update from ``new``,
+    not a scatter into ``vec``: on a pool sharded by rows over several
+    TPU chips, the partitioned scatter wrote rows that belong to one
+    chip into the other chip's rows as well (a 2-chip scheduler served
+    request 2's tokens to request 0), where a select on ``vec``'s own
+    rows and a gather from the replicated ``new`` stay on each chip."""
+    n = vec.shape[0]
+    rows = jnp.asarray(rows, jnp.int32)
+    rows = jnp.where(rows < 0, rows + n, rows)
+    hit = ((rows[None, :] == jnp.arange(n, dtype=jnp.int32)[:, None])
+           & jnp.asarray(mask, bool)[None, :])
+    src = jnp.argmax(hit, axis=1)
+    m = hit.any(axis=1).reshape((n,) + (1,) * (vec.ndim - 1))
+    return jnp.where(m, new.astype(vec.dtype)[src], vec)
 
 
 # =========================== per-layer views ================================
@@ -192,10 +212,15 @@ class DenseView:
 class PagedView:
     """One layer of a paged cache: pool slices plus the shared table.
 
-    ``k_pool``/``v_pool``: ``(n_blocks, block, KV, hd)``. ``table``:
-    ``(n_rows, blocks_per_row)`` physical block ids, ``-1`` where
-    unallocated. ``max_len`` (static) is the logical per-row width
-    ``gather`` reconstructs — matching the dense layout exactly.
+    ``k_pool``/``v_pool``: ``(n_blocks, KV, block, hd)`` — head-major,
+    so one (block, head) tile is a contiguous ``(block, hd)`` slab whose
+    last two dims the TPU compiler accepts as a kernel block (a
+    ``(block, 1, hd)`` slice of a token-major pool is refused: its
+    second-to-last dim is neither a multiple of 8 nor the full KV
+    width). ``table``: ``(n_rows, blocks_per_row)`` physical block ids,
+    ``-1`` where unallocated. ``max_len`` (static) is the logical
+    per-row width ``gather`` reconstructs — matching the dense layout
+    exactly.
     """
 
     k_pool: jax.Array
@@ -221,7 +246,7 @@ class PagedView:
 
     @property
     def block(self) -> int:
-        return self.k_pool.shape[1]
+        return self.k_pool.shape[2]
 
     @property
     def n_blocks(self) -> int:
@@ -260,10 +285,11 @@ class PagedView:
             blk = jnp.where(self.mask[:, None], blk, self.n_blocks)
         fb = blk.reshape(-1)
         fo = off.reshape(-1)
-        kp = self.k_pool.at[fb, fo].set(
+        # pool[fb, :, fo] is (n*C, KV, hd): the token-major chunk rows
+        kp = self.k_pool.at[fb, :, fo].set(
             k.astype(self.k_pool.dtype).reshape((n * C,) + k.shape[2:]),
             mode="drop")
-        vp = self.v_pool.at[fb, fo].set(
+        vp = self.v_pool.at[fb, :, fo].set(
             v.astype(self.v_pool.dtype).reshape((n * C,) + v.shape[2:]),
             mode="drop")
         return dataclasses.replace(self, k_pool=kp, v_pool=vp)
@@ -277,10 +303,10 @@ class PagedView:
         blk, off = self._phys(rows, pos.astype(jnp.int32))
         if self.mask is not None:
             blk = jnp.where(self.mask, blk, self.n_blocks)
-        kp = self.k_pool.at[blk, off].set(k[:, 0].astype(self.k_pool.dtype),
-                                          mode="drop")
-        vp = self.v_pool.at[blk, off].set(v[:, 0].astype(self.v_pool.dtype),
-                                          mode="drop")
+        kp = self.k_pool.at[blk, :, off].set(
+            k[:, 0].astype(self.k_pool.dtype), mode="drop")
+        vp = self.v_pool.at[blk, :, off].set(
+            v[:, 0].astype(self.v_pool.dtype), mode="drop")
         return dataclasses.replace(self, k_pool=kp, v_pool=vp)
 
     def gather(self) -> Tuple[jax.Array, jax.Array]:
@@ -308,12 +334,14 @@ class PagedView:
         """
         table = self.table if self.rows is None else self.table[self.rows]
         safe = jnp.clip(table, 0)
-        kg = self.k_pool[safe]            # (n, bpr, block, KV, hd)
-        vg = self.v_pool[safe]
         n, bpr = table.shape
-        kg = kg.reshape((n, bpr * self.block) + kg.shape[3:])
-        vg = vg.reshape((n, bpr * self.block) + vg.shape[3:])
-        return kg[:, :self.max_len], vg[:, :self.max_len]
+
+        def dense(pool):                  # (n, bpr, KV, block, hd)
+            g = pool[safe].swapaxes(2, 3)
+            return g.reshape((n, bpr * self.block) + g.shape[3:])
+
+        return (dense(self.k_pool)[:, :self.max_len],
+                dense(self.v_pool)[:, :self.max_len])
 
     def paged_state(self):
         """Gather-free kernel operands ``(k_pool, v_pool, table)`` —
@@ -429,7 +457,7 @@ class KVCache:
     def export_rows(self, rows, n_cols: int):
         """Pack the K/V bits behind ``rows``' leading ``n_cols`` table
         columns into a FRESH dense-of-blocks buffer
-        ``(L, len(rows), n_cols, block, KV, hd)`` — the wire format
+        ``(L, len(rows), n_cols, KV, block, hd)`` — the wire format
         for shipping finished prefill blocks to another pool
         (``serve/disagg.py``). Paged only."""
         raise NotImplementedError
@@ -510,7 +538,7 @@ class PagedKVCache(KVCache):
     unambiguous while readers come and go.
     """
 
-    k_pool: jax.Array        # (L, n_blocks, block, KV, hd)
+    k_pool: jax.Array        # (L, n_blocks, KV, block, hd)
     v_pool: jax.Array
     table: jax.Array         # (n_rows, blocks_per_row) int32, -1 = unalloc
     owner: jax.Array         # (n_blocks,) int32, -1 = free
@@ -535,7 +563,7 @@ class PagedKVCache(KVCache):
         that under-provisioning is the whole point."""
         bpr = math.ceil(max_len / block)
         nb = n_rows * bpr if n_blocks is None else int(n_blocks)
-        pshape = (n_layers, nb, block, kv_heads, head_dim)
+        pshape = (n_layers, nb, kv_heads, block, head_dim)
         if abstract:
             e = jax.ShapeDtypeStruct(pshape, dtype)
             vec = jax.ShapeDtypeStruct((nb,), jnp.int32)
@@ -555,7 +583,7 @@ class PagedKVCache(KVCache):
 
     @property
     def block(self) -> int:
-        return self.k_pool.shape[2]
+        return self.k_pool.shape[3]
 
     @property
     def n_blocks(self) -> int:
@@ -656,8 +684,7 @@ class PagedKVCache(KVCache):
         phys = free_ids[jnp.clip(want, 0, self.n_blocks - 1)]
         new_rows = jnp.where(is_shared, shared,
                              jnp.where(is_fresh, phys, -1))
-        table = self.table.at[rows].set(
-            jnp.where(mask[:, None], new_rows, self.table[rows]))
+        table = set_rows(self.table, rows, new_rows, mask)
         # refcount: +1 per mapped entry (shared or fresh), +1 extra per
         # pinned column
         inc = jnp.where(new_rows >= 0,
@@ -685,7 +712,7 @@ class PagedKVCache(KVCache):
         n = self.n_rows
         rows = _bcast_rows(rows, n)
         mask = jnp.ones((rows.shape[0],), bool) if mask is None else mask
-        row_freed = jnp.zeros((n,), bool).at[rows].set(mask, mode="drop")
+        row_freed = set_rows(jnp.zeros((n,), bool), rows, mask, mask)
         ids = jnp.where(row_freed[:, None] & (self.table >= 0),
                         self.table, self.n_blocks)
         dec = jnp.zeros((self.n_blocks,), jnp.int32).at[
@@ -801,7 +828,7 @@ class PagedKVCache(KVCache):
 
     def export_rows(self, rows, n_cols: int):
         """Gather ``rows``' leading ``n_cols`` table columns into fresh
-        ``(L, R, n_cols, block, KV, hd)`` K/V buffers — block-granular,
+        ``(L, R, n_cols, KV, block, hd)`` K/V buffers — block-granular,
         layout-preserving, and *fresh* (no aliasing into the pool), so
         the caller can ``device_put`` the result into another pool's
         sharding while this pool keeps mutating. Unallocated columns
@@ -819,7 +846,7 @@ class PagedKVCache(KVCache):
     def import_rows(self, rows, k_data, v_data,
                     mask=None) -> "PagedKVCache":
         """Scatter ``export_rows``-shaped buffers ``(L, R, n_cols,
-        block, KV, hd)`` into ``rows``' leading table columns — the
+        KV, block, hd)`` into ``rows``' leading table columns — the
         receiving half of a block shipment. The rows must already hold
         fresh allocations (``alloc`` first); columns the destination
         table doesn't back (``-1``) and unmasked rows drop, so a short
@@ -841,7 +868,7 @@ class PagedKVCache(KVCache):
 
     def shardings(self, rules, mesh=None, row_axis: str = sh.BATCH):
         pool = rules.sharding(
-            (sh.LAYERS, sh.BLOCK, None, sh.CACHE_KV, sh.CACHE_HD), mesh,
+            (sh.LAYERS, sh.BLOCK, sh.CACHE_KV, None, sh.CACHE_HD), mesh,
             dims=tuple(self.k_pool.shape))
         vec = rules.sharding((sh.BLOCK,), mesh,
                              dims=tuple(self.owner.shape))

@@ -102,6 +102,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import hashlib
 from typing import Any, List, Optional
 
@@ -394,6 +395,22 @@ def pool_shardings(cfg, n_slots: int, max_len: int, max_new_cap: int,
         slot_layers=vec, slot_decodes=vec)
 
 
+def _under_mesh(fn, mesh):
+    """``fn`` called with ``mesh`` as the abstract context mesh when the
+    pool spans several devices, so the Pallas kernels traced inside can
+    wrap themselves for it (``kernels.per_device``: the TPU compiler
+    cannot partition them). Only the abstract mesh is set: a concrete
+    one would also bind every ``device_put`` traced inside to the mesh's
+    devices. Unchanged on one device."""
+    if mesh is None or mesh.size == 1:
+        return fn
+
+    def call(*args):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return fn(*args)
+    return call
+
+
 # =========================== scheduler ======================================
 
 class DecodeScheduler:
@@ -615,17 +632,18 @@ class DecodeScheduler:
         self.depth_tokens = 0         # Σ decode tokens they cover
 
         self.pool = self._init_pool()
+        on_mesh = functools.partial(_under_mesh, mesh=self.mesh)
         # chunked admission runs NO model forward: assign registers +
         # alloc blocks, and let the in-graph step do the prefilling
-        self._admit_fn = jax.jit(self._build_assign()
-                                 if prefill == "chunked"
-                                 else self._build_admit())
-        self._step_fn = jax.jit(self._build_step())
-        self._preempt_fn = jax.jit(self._build_preempt())
+        self._admit_fn = on_mesh(jax.jit(self._build_assign()
+                                         if prefill == "chunked"
+                                         else self._build_admit()))
+        self._step_fn = on_mesh(jax.jit(self._build_step()))
+        self._preempt_fn = on_mesh(jax.jit(self._build_preempt()))
         # disaggregated decode-tier admission (register + alloc +
         # import shipped blocks); only meaningful for paged chunked
         # pools that DO decode
-        self._splice_fn = (jax.jit(self._build_splice())
+        self._splice_fn = (on_mesh(jax.jit(self._build_splice()))
                            if prefill == "chunked" and kv == "paged"
                            and not prefill_only else None)
 
@@ -739,15 +757,11 @@ class DecodeScheduler:
 
             def splice(full, new):
                 # spliced leaves carry the slot dim at axis 1
-                m = mask.reshape((1, n) + (1,) * (full.ndim - 2))
-                old = jnp.take(full, slots, axis=1)
-                upd = jnp.where(m, new.astype(full.dtype), old)
-                return full.at[:, slots].set(upd)
+                return kvc.set_rows(full.swapaxes(0, 1), slots,
+                                    new.swapaxes(0, 1), mask).swapaxes(0, 1)
 
             def sreg(vec, new):
-                m = mask.reshape((n,) + (1,) * (vec.ndim - 1))
-                return vec.at[slots].set(
-                    jnp.where(m, new.astype(vec.dtype), vec[slots]))
+                return kvc.set_rows(vec, slots, new, mask)
 
             # Attention KVCache entries were written in-pool by prefill
             # (rows/mask-aware); SSM and audio-cross state comes back
@@ -832,9 +846,7 @@ class DecodeScheduler:
                 keys)
 
             def sreg(vec, new):
-                m = mask.reshape((n,) + (1,) * (vec.ndim - 1))
-                return vec.at[slots].set(
-                    jnp.where(m, new.astype(vec.dtype), vec[slots]))
+                return kvc.set_rows(vec, slots, new, mask)
 
             return dataclasses.replace(
                 pool, cache=cache,
@@ -930,7 +942,7 @@ class DecodeScheduler:
                    k_data, v_data) -> SlotPool:
             """slots/mask/rids/... as in ``_assign``; t0 (n,) int32 —
             each spliced request's prefill-sampled first token;
-            k_data/v_data (L, k, n_cols, block, KV, hd) — the shipped
+            k_data/v_data (L, k, n_cols, KV, block, hd) — the shipped
             block buffers for the k masked rows (already placed in
             this pool's sharding by the caller's ``device_put``)."""
             k = k_data.shape[1]
@@ -947,9 +959,7 @@ class DecodeScheduler:
                 keys)
 
             def sreg(vec, new):
-                m = mask.reshape((n,) + (1,) * (vec.ndim - 1))
-                return vec.at[slots].set(
-                    jnp.where(m, new.astype(vec.dtype), vec[slots]))
+                return kvc.set_rows(vec, slots, new, mask)
 
             return dataclasses.replace(
                 pool, cache=cache,
@@ -1043,10 +1053,11 @@ class DecodeScheduler:
         def decode_fn(params, p: SlotPool) -> SlotPool:
             tok = p.next_token                           # (n,)
             emit = p.active
-            row = jnp.arange(n)
             idx = jnp.clip(p.n_emitted, 0, cap - 1)
-            out = p.out.at[row, idx].set(
-                jnp.where(emit, tok, p.out[row, idx]))
+            # a select on each row's own columns, not a scatter (see
+            # kv_cache.set_rows): ``out`` is sharded by slot rows
+            put = emit[:, None] & (jnp.arange(cap)[None, :] == idx[:, None])
+            out = jnp.where(put, tok[:, None].astype(p.out.dtype), p.out)
             n_emitted = p.n_emitted + emit
             finished = emit & ((tok == eos_id)
                                | (n_emitted >= p.budget))
@@ -1158,8 +1169,12 @@ class DecodeScheduler:
             m = jnp.where(emit, m, 0)
             put = emit[:, None] & (jw[None] < m[:, None])
             idx = jnp.where(put, p.n_emitted[:, None] + jw[None], cap)
-            out = p.out.at[row[:, None], idx].set(
-                jnp.where(put, window, 0), mode="drop")
+            # (n, k+1, cap): window lane j lands in column idx[:, j]; a
+            # select per row as in decode_fn, not a scatter
+            hit = idx[:, :, None] == jnp.arange(cap)[None, None, :]
+            out = jnp.where(hit.any(axis=1),
+                            jnp.sum(jnp.where(hit, window[:, :, None], 0),
+                                    axis=1).astype(p.out.dtype), p.out)
             n_emitted = p.n_emitted + m
             last_tok = window[row, jnp.maximum(m - 1, 0)]
             finished = emit & ((last_tok == eos_id)
@@ -1819,7 +1834,7 @@ class DecodeScheduler:
         prefill tier, ``t0s`` their prefill-sampled first tokens,
         ``plens`` their prefilled stream lengths, and
         ``k_data``/``v_data`` the shipped ``(L, len(reqs), n_cols,
-        block, KV, hd)`` block buffers — ideally already
+        KV, block, hd)`` block buffers — ideally already
         ``device_put`` into this pool's sharding, so an async transfer
         overlaps host work and the jitted splice simply consumes it
         when the bits land. The caller gates on ``free_slots`` /

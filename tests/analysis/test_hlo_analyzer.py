@@ -27,8 +27,6 @@ class TestAnalyzer:
         assert L in cost.trip_counts.values()
         # raw cost_analysis counts the body once — the reason we exist
         raw = compiled.cost_analysis()
-        if isinstance(raw, list):  # jax < 0.5 returns one dict per device
-            raw = raw[0]
         assert raw["flops"] < cost.flops / 3
 
     def test_nested_loops_multiply(self):

@@ -21,6 +21,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 def run_ndev(body: str, n_devices: int = 8, timeout: int = 600) -> str:
     script = textwrap.dedent(f"""
         import os
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (
             "--xla_force_host_platform_device_count={n_devices}")
         import sys

@@ -58,8 +58,8 @@ def _paged_case(B, H, KV, hd, block, bpr, dtype, i=0, shuffle=True):
     """Random pool + SHUFFLED block table (the indirection must matter)
     + ragged cur_len including the cur_len=1 edge and a full row."""
     n_blocks = B * bpr + 3                     # spare blocks stay unused
-    kp = rand((n_blocks, block, KV, hd), dtype, 10 + i)
-    vp = rand((n_blocks, block, KV, hd), dtype, 20 + i)
+    kp = rand((n_blocks, KV, block, hd), dtype, 10 + i)
+    vp = rand((n_blocks, KV, block, hd), dtype, 20 + i)
     q = rand((B, 1, H, hd), dtype, 30 + i)
     ids = jax.random.permutation(jax.random.fold_in(KEY, 40 + i), n_blocks)
     if not shuffle:
@@ -100,8 +100,10 @@ class TestPagedAttention:
         q, kp, vp, table, cur = _paged_case(B, H, KV, hd, block, bpr,
                                             jnp.float32)
         out = paged_attention(q, kp, vp, table, cur)
-        kg = kp[jnp.clip(table, 0)].reshape(B, bpr * block, KV, hd)
-        vg = vp[jnp.clip(table, 0)].reshape(B, bpr * block, KV, hd)
+        kg = kp[jnp.clip(table, 0)].swapaxes(2, 3).reshape(
+            B, bpr * block, KV, hd)
+        vg = vp[jnp.clip(table, 0)].swapaxes(2, 3).reshape(
+            B, bpr * block, KV, hd)
         for b in range(int(B)):
             T = int(cur[b])
             ref = attention_ref(q[b:b + 1], kg[b:b + 1, :T],
@@ -132,8 +134,8 @@ class TestPagedAttention:
         outs = []
         for block in (4, 8, 16, 32):
             bpr = T // block
-            kp = k.reshape(B * bpr, block, KV, hd)
-            vp = v.reshape(B * bpr, block, KV, hd)
+            kp = k.reshape(B * bpr, block, KV, hd).swapaxes(1, 2)
+            vp = v.reshape(B * bpr, block, KV, hd).swapaxes(1, 2)
             table = jnp.arange(B * bpr, dtype=jnp.int32).reshape(B, bpr)
             outs.append(paged_attention(q, kp, vp, table, cur))
         for o in outs[1:]:
@@ -267,8 +269,8 @@ def _prefill_case(B, C, H, KV, hd, block, bpr, dtype, i=0):
     the edges: offset 0 (first chunk), a mid-stream offset, and the
     last chunk of a full row."""
     n_blocks = B * bpr + 3
-    kp = rand((n_blocks, block, KV, hd), dtype, 60 + i)
-    vp = rand((n_blocks, block, KV, hd), dtype, 70 + i)
+    kp = rand((n_blocks, KV, block, hd), dtype, 60 + i)
+    vp = rand((n_blocks, KV, block, hd), dtype, 70 + i)
     q = rand((B, C, H, hd), dtype, 80 + i)
     ids = jax.random.permutation(jax.random.fold_in(KEY, 90 + i), n_blocks)
     table = ids[:B * bpr].reshape(B, bpr).astype(jnp.int32)
@@ -308,8 +310,8 @@ class TestFlashPrefill:
         q = rand((B, S, H, hd), jnp.float32, 3)
         ref = attention_ref(q, k, v, causal=True)
         bpr = S // block
-        kp = k.reshape(B * bpr, block, KV, hd)
-        vp = v.reshape(B * bpr, block, KV, hd)
+        kp = k.reshape(B * bpr, block, KV, hd).swapaxes(1, 2)
+        vp = v.reshape(B * bpr, block, KV, hd).swapaxes(1, 2)
         table = jnp.arange(B * bpr, dtype=jnp.int32).reshape(B, bpr)
         for C in (1, block, 5):
             outs = []

@@ -36,8 +36,8 @@ def _verify_case(B, W, H, KV, hd, block, bpr, dtype, i=0):
     the edges: offset 0 (empty history), a mid-block offset (window
     straddles a block seam), and the last window of a full row."""
     n_blocks = B * bpr + 3
-    kp = rand((n_blocks, block, KV, hd), dtype, 10 + i)
-    vp = rand((n_blocks, block, KV, hd), dtype, 20 + i)
+    kp = rand((n_blocks, KV, block, hd), dtype, 10 + i)
+    vp = rand((n_blocks, KV, block, hd), dtype, 20 + i)
     q = rand((B, W, H, hd), dtype, 30 + i)
     ids = jax.random.permutation(jax.random.fold_in(KEY, 40 + i), n_blocks)
     table = ids[:B * bpr].reshape(B, bpr).astype(jnp.int32)

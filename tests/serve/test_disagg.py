@@ -8,6 +8,12 @@ and priority preemption composing unchanged. Tier-1 runs everything
 mesh-less (both tiers on the default device — the ship/splice path is
 fully exercised); the explicit 4+4 submesh split runs in an 8-device
 subprocess (and in CI's 8-virtual-device job).
+
+These identities are asserted on CPU devices. On TPU they hold when
+each tier runs the colocated per-chip program (one chip, or rows
+replicated); a tier that splits rows over several chips does not yet
+reproduce colocated tokens (DESIGN.md §8.7), which
+``chip_smoke.py --chips 4`` checks on the chip.
 """
 
 import dataclasses
@@ -94,8 +100,8 @@ def test_export_import_roundtrip(smollm):
                                  src.v_pool.shape, src.v_pool.dtype))
     n_cols = kvc.blocks_needed(max_len, block)
     k, v = src.export_rows(jnp.arange(rows, dtype=jnp.int32), n_cols)
-    assert k.shape == (src.k_pool.shape[0], rows, n_cols, block,
-                       src.k_pool.shape[3], src.k_pool.shape[4])
+    assert k.shape == (src.k_pool.shape[0], rows, n_cols,
+                       src.k_pool.shape[2], block, src.k_pool.shape[4])
 
     dst = engine.make_cache(cfg, rows, max_len, kv_impl="paged",
                             kv_block=block)[key]
@@ -121,8 +127,8 @@ def test_import_rows_masked_rows_untouched(smollm):
                         jnp.full((rows,), max_len, jnp.int32))
     before = np.asarray(cache.k_pool)
     n_cols = kvc.blocks_needed(max_len, block)
-    k = jnp.ones((cache.k_pool.shape[0], rows, n_cols, block,
-                  cache.k_pool.shape[3], cache.k_pool.shape[4]),
+    k = jnp.ones((cache.k_pool.shape[0], rows, n_cols,
+                  cache.k_pool.shape[2], block, cache.k_pool.shape[4]),
                  cache.k_pool.dtype)
     out = cache.import_rows(jnp.arange(rows, dtype=jnp.int32), k, k,
                             mask=jnp.asarray([True, False]))
@@ -321,7 +327,7 @@ def test_ship_path_never_materializes_dense_kv(smollm):
     cache = cache.alloc(jnp.arange(rows, dtype=jnp.int32),
                         jnp.full((rows,), max_len, jnp.int32))
     n_cols = kvc.blocks_needed(max_len, block)
-    kvh, hd = cache.k_pool.shape[3], cache.k_pool.shape[4]
+    kvh, hd = cache.k_pool.shape[2], cache.k_pool.shape[4]
     r = jnp.arange(rows, dtype=jnp.int32)
 
     def ship(src, dst):
@@ -331,7 +337,7 @@ def test_ship_path_never_materializes_dense_kv(smollm):
     assert _dense_kv_eqns(ship, (cache, cache), rows=rows,
                           max_len=max_len, kv=kvh, hd=hd) == 0
     assert _dense_kv_eqns(
-        lambda s: (s.export_rows(r, n_cols)[0][0]
+        lambda s: (s.export_rows(r, n_cols)[0][0].swapaxes(2, 3)
                    .reshape(rows, n_cols * block, kvh, hd)),
         (cache,), rows=rows, max_len=max_len, kv=kvh, hd=hd) > 0
 
@@ -392,3 +398,69 @@ assert d.transfer_impl == "device_put:ics"
 print("DISAGG_8DEV_OK")
 """)
     assert "DISAGG_8DEV_OK" in out
+
+
+def test_disagg_2plus2_pallas_kernels_split_rows():
+    """Pallas kernels (interpret mode here) under each tier's two-device
+    mesh: ``kernels.per_device`` splits the batch rows over the slice's
+    data axis, and the streams stay bit-identical to the mesh-less
+    colocated run of the same kernels."""
+    out = run_ndev("""
+import dataclasses
+from repro.configs import get_config
+from repro.models import model_zoo
+from repro.dist import sharding as sh
+from repro.kernels import per_device
+from repro.kernels.paged_attention import ops as pa
+from repro.serve import scheduler as sched_lib
+from repro.serve import disagg as disagg_lib
+
+# the wrapper alone: rows split over a 2-device mesh == unsplit
+mesh = sh.slice_mesh(jax.devices()[:2])
+key = jax.random.PRNGKey(0)
+q = jax.random.normal(key, (4, 1, 4, 16), jnp.float32)
+kp = jax.random.normal(jax.random.fold_in(key, 1), (12, 2, 4, 16))
+vp = jax.random.normal(jax.random.fold_in(key, 2), (12, 2, 4, 16))
+table = jnp.arange(12, dtype=jnp.int32).reshape(4, 3)
+cur = jnp.array([1, 5, 9, 12], jnp.int32)
+ref = pa.paged_attention(q, kp, vp, table, cur)
+on = lambda a: jax.device_put(a, jax.NamedSharding(
+    mesh, jax.P("data")))      # rows, and the pools' blocks, as served
+with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+    got = jax.jit(pa.paged_attention)(*map(on, (q, kp, vp, table, cur)))
+    text = jax.jit(lambda *a: per_device(lambda x: x * 2, rows=(0,))(*a)
+                   ).lower(q).as_text()
+np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+assert "shard_map" in text or "sdy.manual_computation" in text, text
+
+cfg = dataclasses.replace(get_config("smollm-135m", smoke=True),
+                          attn_impl="pallas")
+KEY = jax.random.PRNGKey(3)
+params = model_zoo.init_params(cfg, KEY)
+pf_dev, de_dev = sh.carve_slices(2)
+pf_mesh, de_mesh = sh.slice_mesh(pf_dev), sh.slice_mesh(de_dev)
+prompts = [jax.random.randint(jax.random.fold_in(KEY, b), (1, L), 2,
+                              cfg.vocab)
+           for b, L in enumerate((3, 9, 16, 12))]
+co = sched_lib.DecodeScheduler(
+    params, cfg, n_slots=2, prompt_len=16, max_new_cap=5, eos_id=1,
+    kv="paged", kv_block=4, prefill="chunked", chunk_tokens=8)
+for b, p in enumerate(prompts):
+    co.submit(p, max_new=5, request_id=b)
+ref = {f.request_id: np.asarray(f.tokens)
+       for f in co.run_until_drained()}
+d = disagg_lib.DisaggScheduler(
+    params, cfg, n_prefill_slots=2, n_decode_slots=2, prompt_len=16,
+    max_new_cap=5, eos_id=1, prefill_mesh=pf_mesh, decode_mesh=de_mesh,
+    kv_block=4, chunk_tokens=8)
+for b, p in enumerate(prompts):
+    d.submit(p, max_new=5, request_id=b)
+got = {f.request_id: np.asarray(f.tokens)
+       for f in d.run_until_drained()}
+assert got.keys() == ref.keys()
+for rid in ref:
+    np.testing.assert_array_equal(got[rid], ref[rid])
+assert d.attn_impl == "pallas-paged:interpret", d.attn_impl
+print("DISAGG_PALLAS_OK")
+""", n_devices=4)
+    assert "DISAGG_PALLAS_OK" in out

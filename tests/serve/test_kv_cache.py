@@ -75,7 +75,7 @@ def test_append_to_freed_row_is_dropped():
     row1_block = int(np.asarray(c.table)[1, 0])
     row0_block = 1 - row1_block
     # row 1's write landed; row 0's old block untouched
-    assert (pool_after[0, row1_block, 1] == 7).all()
+    assert (pool_after[0, row1_block, :, 1] == 7).all()
     np.testing.assert_array_equal(pool_after[0, row0_block],
                                   pool_before[0, row0_block])
 
@@ -172,3 +172,19 @@ def test_cache_rides_through_jit_and_while_loop():
     k, _ = c2.gather(0)
     np.testing.assert_array_equal(np.asarray(k)[0, :4, 0, 0],
                                   np.arange(4, dtype=np.float32))
+
+
+def test_set_rows_matches_masked_scatter():
+    """``set_rows`` (the scheduler's slot-row write) equals the masked
+    scatter it replaces, for permuted rows, negative rows and rows out
+    of range."""
+    rng = np.random.default_rng(0)
+    vec = jnp.asarray(rng.integers(0, 100, (6, 3)), jnp.int32)
+    new = jnp.asarray(rng.integers(100, 200, (5, 3)), jnp.int32)
+    mask = jnp.array([True, False, True, True, True])
+    for rows in ([4, 0, 2, 5, 1], [3, 1, -1, 0, 9]):
+        r = jnp.asarray(rows, jnp.int32)
+        want = vec.at[r].set(jnp.where(mask[:, None], new, vec[r]),
+                             mode="drop")
+        np.testing.assert_array_equal(
+            np.asarray(kvc.set_rows(vec, r, new, mask)), np.asarray(want))

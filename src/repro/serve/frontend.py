@@ -34,8 +34,11 @@ client never sees a duplicate or a gap.
 from __future__ import annotations
 
 import asyncio
+import functools
 import json
 from typing import AsyncIterator, Dict, Optional
+
+import jax
 
 from . import slo as slo_lib
 
@@ -113,27 +116,33 @@ class StreamingFrontend:
                     q.put_nowait({"event": "error",  # don't hang them
                                   "message": repr(exc)})
                 raise
-            for e in events:
-                q = self._queues.get(e.request_id)
-                if q is None:
-                    continue
-                if e.kind == "token":
+            self._fan_out(events)
+
+    @functools.partial(jax.profiler.annotate_function,
+                       name="serve.fanout")
+    def _fan_out(self, events) -> None:
+        """Put one round's events on their consumers' queues."""
+        for e in events:
+            q = self._queues.get(e.request_id)
+            if q is None:
+                continue
+            if e.kind == "token":
+                q.put_nowait({"event": "token",
+                              "request_id": e.request_id,
+                              "tokens": e.tokens})
+            elif e.kind == "preempted":
+                q.put_nowait({"event": "preempted",
+                              "request_id": e.request_id})
+            elif e.kind == "finished":
+                if e.tokens:
                     q.put_nowait({"event": "token",
                                   "request_id": e.request_id,
                                   "tokens": e.tokens})
-                elif e.kind == "preempted":
-                    q.put_nowait({"event": "preempted",
-                                  "request_id": e.request_id})
-                elif e.kind == "finished":
-                    if e.tokens:
-                        q.put_nowait({"event": "token",
-                                      "request_id": e.request_id,
-                                      "tokens": e.tokens})
-                    f = e.finished
-                    q.put_nowait({"event": "done",
-                                  "request_id": e.request_id,
-                                  "length": f.length,
-                                  "hit_eos": bool(f.hit_eos)})
+                f = e.finished
+                q.put_nowait({"event": "done",
+                              "request_id": e.request_id,
+                              "length": f.length,
+                              "hit_eos": bool(f.hit_eos)})
 
     # ---------------- client API --------------------------------------
 

@@ -104,6 +104,7 @@ import collections
 import dataclasses
 import functools
 import hashlib
+import time
 from typing import Any, List, Optional
 
 import jax
@@ -175,6 +176,11 @@ class SlotPool:
                              # (adaptive depth; == L·decodes otherwise)
     slot_decodes: Any = None  # (n,) int32 — Σ decode tokens the depth
                              # sum covers (mean depth = layers/decodes)
+    chunk_iters: Any = None  # scalar int32 — loop iterations whose chunk
+                             # branch ran (monotonic: reset_stats
+                             # leaves it, as it leaves chunk_rows)
+    chunk_rows: Any = None   # scalar int32 — Σ prefilling rows at those
+                             # iterations (the chunk forward runs all n)
 
     def tree_flatten(self):
         return (self.cache, self.next_token, self.cur_len, self.n_emitted,
@@ -183,7 +189,8 @@ class SlotPool:
                 self.prompt, self.plen, self.pf_pos, self.prefilling,
                 self.prefix, self.draft, self.slot_accepted,
                 self.slot_windows, self.priority, self.deadline,
-                self.slot_layers, self.slot_decodes), None
+                self.slot_layers, self.slot_decodes, self.chunk_iters,
+                self.chunk_rows), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -212,6 +219,8 @@ class _Queued:
     frames: Any = None
     priority: int = 0        # lower = more urgent (SLO layer)
     deadline: float = float("inf")
+    admitted_wall: Optional[float] = None   # time.monotonic() when
+                             # _admit_queued first gave it a slot
 
 
 @dataclasses.dataclass
@@ -392,7 +401,8 @@ def pool_shardings(cfg, n_slots: int, max_len: int, max_new_cap: int,
             row_axis=sh.SLOT) if draft_cfg is not None else None),
         slot_accepted=vec, slot_windows=vec,
         priority=vec, deadline=vec,
-        slot_layers=vec, slot_decodes=vec)
+        slot_layers=vec, slot_decodes=vec,
+        chunk_iters=rep, chunk_rows=rep)
 
 
 def _under_mesh(fn, mesh):
@@ -688,7 +698,9 @@ class DecodeScheduler:
             priority=jnp.zeros((n,), jnp.int32),
             deadline=jnp.full((n,), jnp.inf, jnp.float32),
             slot_layers=jnp.zeros((n,), jnp.int32),
-            slot_decodes=jnp.zeros((n,), jnp.int32))
+            slot_decodes=jnp.zeros((n,), jnp.int32),
+            chunk_iters=jnp.asarray(0, jnp.int32),
+            chunk_rows=jnp.asarray(0, jnp.int32))
         if self.rules is not None and self.mesh is not None \
                 and self.mesh.size > 1:
             shd = pool_shardings(self.cfg, n, self.max_len, cap,
@@ -1247,21 +1259,32 @@ class DecodeScheduler:
                                        done=jnp.zeros_like(pool.done))
 
             def body_fn(p: SlotPool) -> SlotPool:
+                # the scopes name each branch's ops in the compiled
+                # program's metadata, and so in a device trace
                 if chunked:
-                    p = jax.lax.cond(jnp.any(p.prefilling),
-                                     lambda q: chunk_fn(params, dparams,
-                                                        q),
-                                     lambda q: q, p)
+                    rows = jnp.sum(p.prefilling).astype(jnp.int32)
+                    with jax.named_scope("serve.prefill_chunk"):
+                        p = jax.lax.cond(rows > 0,
+                                         lambda q: chunk_fn(params, dparams,
+                                                            q),
+                                         lambda q: q, p)
                     # decode only when someone is actually running
                     # (pure-prefill iterations skip the dispatch; a
                     # slot that just finished its chunk decodes NOW)
                     dec = (spec_decode_fn if spec is not None
                            else lambda pp, dd, q: decode_fn(pp, q))
-                    p = jax.lax.cond(jnp.any(p.active),
-                                     lambda q: dec(params, dparams, q),
-                                     lambda q: q, p)
+                    with jax.named_scope("serve.decode"):
+                        p = jax.lax.cond(jnp.any(p.active),
+                                         lambda q: dec(params, dparams, q),
+                                         lambda q: q, p)
+                    # how many of the chunk forward's n rows did work
+                    p = dataclasses.replace(
+                        p, chunk_iters=p.chunk_iters
+                        + (rows > 0).astype(jnp.int32),
+                        chunk_rows=p.chunk_rows + rows)
                 else:
-                    p = decode_fn(params, p)
+                    with jax.named_scope("serve.decode"):
+                        p = decode_fn(params, p)
                 # steps counts LOOP iterations — including chunked
                 # mode's prefill-only ones, so per-iteration wall
                 # derivations and occupancy denominators stay honest
@@ -1460,6 +1483,7 @@ class DecodeScheduler:
                     if e is not None:
                         e.ready = True
 
+    @functools.partial(jax.profiler.annotate_function, name="serve.admit")
     def _admit_queued(self) -> int:
         """Fill free slots from the queue in ONE batched prefill.
 
@@ -1626,7 +1650,10 @@ class DecodeScheduler:
                                        true_lens, slots, rids, max_news,
                                        keys, derive, mask, prios,
                                        deadlines, prefix_embeds, frames)
+        now = time.monotonic()
         for i, q in enumerate(batch):
+            if q.admitted_wall is None:
+                q.admitted_wall = now
             slot = int(free[i])
             self._busy[slot] = True
             self._slot_req[slot] = q
@@ -1649,6 +1676,8 @@ class DecodeScheduler:
         self.peak_resident = max(self.peak_resident, self.active_count)
         return k
 
+    @functools.partial(jax.profiler.annotate_function,
+                       name="serve.harvest")
     def _harvest(self) -> List[FinishedRequest]:
         done = np.asarray(self.pool.done)
         if not done.any():
@@ -1724,8 +1753,9 @@ class DecodeScheduler:
                      if self.queue else self.admit_threshold)
             want = self.free_slots + fresh
         cap = _NO_STEP_CAP if max_steps is None else np.int32(max_steps)
-        self.pool = self._step_fn(self.params, self._draft_params,
-                                  self.pool, np.int32(want), cap)
+        with jax.profiler.TraceAnnotation("serve.dispatch"):
+            self.pool = self._step_fn(self.params, self._draft_params,
+                                      self.pool, np.int32(want), cap)
         return True
 
     def step(self, expect_arrivals: bool = False,
@@ -1755,7 +1785,8 @@ class DecodeScheduler:
             return []
         # one post-segment sync (needed before harvest anyway); busy
         # slot-steps accumulate in-graph next to `steps`
-        self.total_steps = int(self.pool.steps)
+        with jax.profiler.TraceAnnotation("serve.segment"):
+            self.total_steps = int(self.pool.steps)
         if self.prefill_only:
             return []
         return self._harvest()
@@ -2012,7 +2043,8 @@ class DecodeScheduler:
         so back-to-back ``run_until_drained`` calls (or ``generate``
         wrappers) each report their own counters without the caller
         doing anything. Manual ``step()`` driving mid-run is
-        unaffected: the scheduler is not idle then."""
+        unaffected: the scheduler is not idle then. The lifetime
+        counters of ``counters()`` are left as they are."""
         self.total_steps = 0
         self.tokens_emitted = 0
         self.peak_resident = 0
@@ -2033,6 +2065,17 @@ class DecodeScheduler:
             slot_windows=z(self.pool.slot_windows),
             slot_layers=z(self.pool.slot_layers),
             slot_decodes=z(self.pool.slot_decodes))
+
+    def counters(self) -> dict:
+        """Lifetime counters, which ``reset_stats()`` never zeroes:
+        ``chunk_iters`` (loop iterations that ran the chunk forward) and
+        ``chunk_rows`` (Σ prefilling rows at those iterations, of the
+        ``n_slots`` the forward computes). Read from the device in one
+        transfer, so only when asked. They are int32 in-graph: take
+        differences modulo 2**32."""
+        it, rows = jax.device_get((self.pool.chunk_iters,
+                                   self.pool.chunk_rows))
+        return {"chunk_iters": int(it), "chunk_rows": int(rows)}
 
     def run_until_drained(self) -> List[FinishedRequest]:
         """Drive until queue and pool are empty; returns all finished."""

@@ -55,11 +55,14 @@ experiences). ``json_summary`` reports per-class p50/p99 of each.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import functools
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
+import jax
 import numpy as np
 
 from . import scheduler as sched_lib
@@ -117,6 +120,7 @@ class _ReqState:
     arrival_wall: float
     arrival_step: int
     delivered: int = 0            # tokens already surfaced to events
+    admitted_wall: Optional[float] = None   # first given a slot
     first_token_step: Optional[int] = None
     first_token_wall: Optional[float] = None
     last_emit_step: int = 0
@@ -136,7 +140,14 @@ class SLOScheduler:
 
     Thread safety: ``submit`` and ``step`` serialize on one lock, so
     an asyncio front-end may submit from the event loop while ``step``
-    runs in a worker thread (``repro.serve.frontend``).
+    runs in a worker thread (``repro.serve.frontend``). How long
+    ``submit`` waited for it accumulates in ``submit_lock_wait_s``
+    (over ``submits`` calls); neither is ever zeroed.
+
+    Every wall stamp is ``time.monotonic()`` seconds:
+    ``request_stamps()`` gives each in-flight request's and the
+    ``STAMP_HISTORY`` most recently finished requests' arrival, first
+    admission, first token and finish.
 
     Args:
       inner: the engine. Its queue must be empty (the SLO layer owns
@@ -146,6 +157,8 @@ class SLOScheduler:
       classes: optional name → :class:`SLOClass` registry for
         ``submit(slo_class="interactive")`` string lookups.
     """
+
+    STAMP_HISTORY = 4096      # finished requests whose stamps are kept
 
     def __init__(self, inner: sched_lib.DecodeScheduler, *,
                  segment_steps: int = 8,
@@ -171,9 +184,14 @@ class SLOScheduler:
         self.preemptions = 0
         self.replay_mismatches = 0    # MUST stay 0: bit-identity broken
         self.completed = 0
+        self.submits = 0
+        self.submit_lock_wait_s = 0.0
+        self._finished_stamps: "collections.deque[dict]" = \
+            collections.deque(maxlen=self.STAMP_HISTORY)
 
     # ---------------- submission --------------------------------------
 
+    @functools.partial(jax.profiler.annotate_function, name="serve.submit")
     def submit(self, prompt, *, max_new: int, slo_class="batch",
                deadline: Optional[float] = None, request_id=None,
                key=None, prefix_embeds=None, frames=None) -> int:
@@ -191,6 +209,8 @@ class SLOScheduler:
             deadline = (now + cls.ttft_budget
                         if cls.ttft_budget is not None else float("inf"))
         with self._lock:
+            self.submit_lock_wait_s += time.monotonic() - now
+            self.submits += 1
             # a submit onto a fully drained inner scheduler resets its
             # per-run stats (scheduler.reset_stats); re-anchor the
             # layer's step clock so _advance_clock's delta stays exact
@@ -220,6 +240,7 @@ class SLOScheduler:
 
     # ---------------- scheduling round --------------------------------
 
+    @functools.partial(jax.profiler.annotate_function, name="serve.round")
     def step(self) -> List[Event]:
         """One SLO round: sort → preempt if needed → admit → bounded
         device segment → observe tokens/finishes. Returns the round's
@@ -230,11 +251,16 @@ class SLOScheduler:
             self._backlog.sort(key=lambda q: (q.priority, q.deadline,
                                               q.request_id))
             events.extend(self._maybe_preempt())
+            fed = list(self._backlog)
             self.inner.queue.extend(self._backlog)
             self._backlog.clear()
             finished = self.inner.step(
                 expect_arrivals=bool(self.inner.queue),
                 max_steps=self.segment_steps)
+            for q in fed:           # the inner scheduler stamps admission
+                st = self._req.get(q.request_id)
+                if st is not None and st.admitted_wall is None:
+                    st.admitted_wall = q.admitted_wall
             # the inner FIFO is drained back every round so NEW
             # arrivals re-sort against what it couldn't admit
             self._backlog.extend(self.inner.queue)
@@ -266,6 +292,8 @@ class SLOScheduler:
 
     # ---------------- preemption planning -----------------------------
 
+    @functools.partial(jax.profiler.annotate_function,
+                       name="serve.preempt")
     def _maybe_preempt(self) -> List[Event]:
         """Evict strictly-lower-priority residents when the most urgent
         backlogged request cannot be admitted. The plan is computed
@@ -363,6 +391,8 @@ class SLOScheduler:
         st.delivered = n
         return [int(t) for t in fresh]
 
+    @functools.partial(jax.profiler.annotate_function,
+                       name="serve.observe")
     def _observe(self, finished) -> List[Event]:
         inner = self.inner
         events: List[Event] = []
@@ -376,6 +406,8 @@ class SLOScheduler:
             mx = self._metrics_for(st.cls.name)
             mx["completed"] += 1
             mx["preempted_times"] += st.n_preempts
+            self._finished_stamps.append(
+                self._stamps(f.request_id, st, done=time.monotonic()))
             del self._req[f.request_id]
         resident = [s for s in range(inner.n_slots)
                     if inner._busy[s] and inner._slot_req[s] is not None]
@@ -392,6 +424,22 @@ class SLOScheduler:
         return events
 
     # ---------------- metrics -----------------------------------------
+
+    @staticmethod
+    def _stamps(rid: int, st: _ReqState, done: Optional[float]) -> dict:
+        return {"request_id": rid, "arrival": st.arrival_wall,
+                "admitted": st.admitted_wall,
+                "first_token": st.first_token_wall, "done": done}
+
+    def request_stamps(self) -> List[dict]:
+        """Wall stamps (``time.monotonic()`` s; None where not yet
+        reached) of the most recently finished requests, oldest first,
+        then of every request in flight: ``request_id``, ``arrival``
+        (submission), ``admitted`` (first given a slot), ``first_token``
+        and ``done``."""
+        with self._lock:
+            return list(self._finished_stamps) + [
+                self._stamps(rid, st, None) for rid, st in self._req.items()]
 
     def _metrics_for(self, name: str) -> dict:
         if name not in self._metrics:

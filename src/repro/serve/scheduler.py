@@ -181,6 +181,12 @@ class SlotPool:
                              # leaves it, as it leaves chunk_rows)
     chunk_rows: Any = None   # scalar int32 — Σ prefilling rows at those
                              # iterations (the chunk forward runs all n)
+    decode_iters: Any = None  # scalar int32 — loop iterations whose
+                             # decode branch ran (monotonic, as above)
+    decode_pages: Any = None  # scalar int32 — Σ over the n rows of
+                             # ceil(cur_len / kv_block) at those
+                             # iterations: the pages the decode kernel
+                             # reads per layer (0 for a dense cache)
 
     def tree_flatten(self):
         return (self.cache, self.next_token, self.cur_len, self.n_emitted,
@@ -190,7 +196,7 @@ class SlotPool:
                 self.prefix, self.draft, self.slot_accepted,
                 self.slot_windows, self.priority, self.deadline,
                 self.slot_layers, self.slot_decodes, self.chunk_iters,
-                self.chunk_rows), None
+                self.chunk_rows, self.decode_iters, self.decode_pages), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -402,7 +408,7 @@ def pool_shardings(cfg, n_slots: int, max_len: int, max_new_cap: int,
         slot_accepted=vec, slot_windows=vec,
         priority=vec, deadline=vec,
         slot_layers=vec, slot_decodes=vec,
-        chunk_iters=rep, chunk_rows=rep)
+        chunk_iters=rep, chunk_rows=rep, decode_iters=rep, decode_pages=rep)
 
 
 def _under_mesh(fn, mesh):
@@ -700,7 +706,9 @@ class DecodeScheduler:
             slot_layers=jnp.zeros((n,), jnp.int32),
             slot_decodes=jnp.zeros((n,), jnp.int32),
             chunk_iters=jnp.asarray(0, jnp.int32),
-            chunk_rows=jnp.asarray(0, jnp.int32))
+            chunk_rows=jnp.asarray(0, jnp.int32),
+            decode_iters=jnp.asarray(0, jnp.int32),
+            decode_pages=jnp.asarray(0, jnp.int32))
         if self.rules is not None and self.mesh is not None \
                 and self.mesh.size > 1:
             shd = pool_shardings(self.cfg, n, self.max_len, cap,
@@ -1011,6 +1019,7 @@ class DecodeScheduler:
         spec = self.speculative
         d_cfg = self.draft_cfg
         prefix_len = self.prefix_len
+        page = self.kv_block if self.kv == "paged" else 0
         if chunked:
             stream = self.prompt_len + self.prefix_len
             # A valid bound for the speculative path too: every verify
@@ -1258,6 +1267,16 @@ class DecodeScheduler:
             pool = dataclasses.replace(pool,
                                        done=jnp.zeros_like(pool.done))
 
+            def count_decode(p: SlotPool) -> SlotPool:
+                # the live pages the decode attention reads per layer,
+                # taken from the cur_len it is handed
+                on = jnp.any(p.active)
+                pages = (jnp.sum(-(-p.cur_len // page)).astype(jnp.int32)
+                         if page else 0)
+                return dataclasses.replace(
+                    p, decode_iters=p.decode_iters + on.astype(jnp.int32),
+                    decode_pages=p.decode_pages + jnp.where(on, pages, 0))
+
             def body_fn(p: SlotPool) -> SlotPool:
                 # the scopes name each branch's ops in the compiled
                 # program's metadata, and so in a device trace
@@ -1273,6 +1292,7 @@ class DecodeScheduler:
                     # slot that just finished its chunk decodes NOW)
                     dec = (spec_decode_fn if spec is not None
                            else lambda pp, dd, q: decode_fn(pp, q))
+                    p = count_decode(p)
                     with jax.named_scope("serve.decode"):
                         p = jax.lax.cond(jnp.any(p.active),
                                          lambda q: dec(params, dparams, q),
@@ -1283,6 +1303,7 @@ class DecodeScheduler:
                         + (rows > 0).astype(jnp.int32),
                         chunk_rows=p.chunk_rows + rows)
                 else:
+                    p = count_decode(p)
                     with jax.named_scope("serve.decode"):
                         p = decode_fn(params, p)
                 # steps counts LOOP iterations — including chunked
@@ -2068,14 +2089,19 @@ class DecodeScheduler:
 
     def counters(self) -> dict:
         """Lifetime counters, which ``reset_stats()`` never zeroes:
-        ``chunk_iters`` (loop iterations that ran the chunk forward) and
+        ``chunk_iters`` (loop iterations that ran the chunk forward),
         ``chunk_rows`` (Σ prefilling rows at those iterations, of the
-        ``n_slots`` the forward computes). Read from the device in one
-        transfer, so only when asked. They are int32 in-graph: take
-        differences modulo 2**32."""
-        it, rows = jax.device_get((self.pool.chunk_iters,
-                                   self.pool.chunk_rows))
-        return {"chunk_iters": int(it), "chunk_rows": int(rows)}
+        ``n_slots`` the forward computes), ``decode_iters`` (loop
+        iterations that ran the decode branch) and ``decode_pages`` (Σ
+        over the ``n_slots`` rows of ``ceil(cur_len / kv_block)`` at
+        those iterations: the pages the decode kernel reads per layer;
+        0 for a dense cache). Read from the device in one transfer, so
+        only when asked. They are int32 in-graph: take differences
+        modulo 2**32."""
+        names = ("chunk_iters", "chunk_rows", "decode_iters",
+                 "decode_pages")
+        vals = jax.device_get(tuple(getattr(self.pool, k) for k in names))
+        return {k: int(v) for k, v in zip(names, vals)}
 
     def run_until_drained(self) -> List[FinishedRequest]:
         """Drive until queue and pool are empty; returns all finished."""

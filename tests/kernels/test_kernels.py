@@ -11,6 +11,7 @@ from repro.kernels.flash_attention.ops import attention_ref, flash_attention
 from repro.kernels.flash_prefill.ops import flash_prefill, \
     flash_prefill_ref
 from repro.kernels.lstm_cell.ops import lstm_cell, lstm_cell_ref
+from repro.kernels.paged_attention import kernel as pa_kernel
 from repro.kernels.paged_attention.ops import paged_attention, \
     paged_attention_ref
 from repro.kernels.selective_scan.ops import selective_scan, \
@@ -142,15 +143,15 @@ class TestPagedAttention:
             np.testing.assert_allclose(o, outs[0], rtol=2e-5, atol=2e-5)
 
     @pytest.mark.parametrize("B,H,KV,hd,block,bpr", [
-        (3, 16, 1, 32, 4, 5),    # MQA, G=16 -> two 8-row query tiles
+        (3, 16, 1, 32, 4, 5),    # MQA, G=16: two sublane tiles of queries
         (2, 32, 2, 16, 8, 3),    # GQA 16:1 over 2 KV heads
-        (2, 24, 2, 16, 4, 4),    # G=12: ragged width keeps one tile
+        (2, 24, 2, 16, 4, 4),    # G=12: a ragged sublane tile
     ])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_wide_gqa_multi_query_tiling(self, B, H, KV, hd, block, bpr,
                                          dtype):
-        """Wide GQA groups (G > 8) split over the multi-query grid
-        axis; parity must hold across the tile seam."""
+        """Wide GQA groups (G > 8): a row's whole (G, hd) query of each
+        KV head sits in one step; parity must hold past 8 query rows."""
         q, kp, vp, table, cur = _paged_case(B, H, KV, hd, block, bpr,
                                             dtype, i=3)
         out = paged_attention(q, kp, vp, table, cur)
@@ -159,6 +160,58 @@ class TestPagedAttention:
         np.testing.assert_allclose(out.astype(np.float32),
                                    ref.astype(np.float32),
                                    rtol=tol, atol=tol)
+
+    @pytest.mark.parametrize("bpr,KV,block,hd,itemsize,want", [
+        (128, 16, 16, 128, 2, 8),     # olmo-1b: 8 pages, 128 tokens
+        (97, 4, 16, 128, 2, 32),      # qwen2-7b-pp4: 32 pages, 512 tokens
+        (8, 3, 16, 128, 2, 8),        # hd 64 padded to 128 lanes: the row
+        (3, 64, 64, 128, 4, 1),       # a page above the chunk: one page
+    ])
+    def test_pages_per_chunk_follows_the_shapes(self, bpr, KV, block, hd,
+                                                itemsize, want):
+        assert pa_kernel.pages_per_chunk(bpr, KV, block, hd,
+                                         itemsize) == want
+
+    @pytest.mark.parametrize("H,KV,block,bpr", [
+        (16, 16, 16, 19),    # MHA, G 1: P 4 (fp32) / 8 (bf16)
+        (28, 4, 16, 67),     # GQA 7:1: P 16 / 32
+        (16, 1, 64, 67),     # MQA, G 16: P 16 / 32
+    ])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_chunk_edges(self, H, KV, block, bpr, dtype):
+        """Live page counts at every chunk edge: none, a single page,
+        below P, P, one past it, a multiple of P and one past that, the
+        whole row (P does not divide it), with partial last pages,
+        ``-1`` entries past the live pages and a shuffled table; and a
+        row whose ``cur_len`` runs past the table, which reads every
+        page once, as the reference does."""
+        hd = 128
+        P = pa_kernel.pages_per_chunk(bpr, KV, block, hd,
+                                      jnp.dtype(dtype).itemsize)
+        assert 1 < P < bpr and bpr % P
+        pages = np.asarray(sorted({n for n in (0, 1, P - 1, P, P + 1,
+                                               2 * P, 2 * P + 1, bpr)
+                                   if n <= bpr}), np.int32)
+        pages = np.append(pages, bpr)
+        B = len(pages)
+        n_blocks = B * bpr + 3
+        kp = rand((n_blocks, KV, block, hd), dtype, 11)
+        vp = rand((n_blocks, KV, block, hd), dtype, 21)
+        q = rand((B, 1, H, hd), dtype, 31)
+        ids = jax.random.permutation(jax.random.fold_in(KEY, 41), n_blocks)
+        table = np.array(ids[:B * bpr].reshape(B, bpr), np.int32)
+        table[np.arange(bpr)[None, :] >= pages[:, None]] = -1
+        # every third row fills its last page; the others end inside it
+        short = (np.arange(B) % 3) * (block // 3)
+        cur = np.where(pages > 0, pages * block - short, 0).astype(np.int32)
+        cur[-1] = (bpr + 1) * block + 3
+        out = paged_attention(q, kp, vp, table, cur).astype(np.float32)
+        ref = paged_attention_ref(q, kp, vp, table, cur).astype(np.float32)
+        live = cur > 0
+        tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+        np.testing.assert_allclose(out[live], ref[live], rtol=tol, atol=tol)
+        # an empty row issues no DMA and writes zeros
+        np.testing.assert_array_equal(out[~live], 0)
 
     def test_matches_paged_view_gather_path(self):
         """Kernel vs the serving stack's own XLA gather path: the same

@@ -84,14 +84,49 @@ def _paged_args(sharding, H, KV, hd, C):
             s((B, BPR), jnp.int32), s((B,), jnp.int32))
 
 
-@pytest.mark.parametrize("width", sorted(WIDTHS))
+# decode kernel cases: a width at the small table above, and the two
+# benchmark cells' real shapes (rows, blocks per row, pool blocks), whose
+# chunks of pages set the kernel's VMEM buffers
+PAGED = {w: (w, B, BPR, N_BLOCKS) for w in WIDTHS}
+PAGED.update({"olmo-1b.chat": ("olmo-1b", 32, 128, 800),
+              "qwen2-7b-pp4.decode_batch": ("gqa-kv4", 128, 97, 2800)})
+
+
+@pytest.mark.parametrize("width", sorted(PAGED))
 def test_paged_attention_compiles(width, one_chip, no_compile_cache,
                                   tpu_ops):
     _, _, pa = tpu_ops
-    H, KV, hd = WIDTHS[width]
-    text = _compiled_text(pa.paged_attention,
-                          *_paged_args(one_chip, H, KV, hd, 1))
+    name, b, bpr, n_blocks = PAGED[width]
+    H, KV, hd = WIDTHS[name]
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    pool = s((n_blocks, KV, BLOCK, hd), jnp.bfloat16)
+    text = _compiled_text(pa.paged_attention, s((b, 1, H, hd), jnp.bfloat16),
+                          pool, pool, s((b, bpr), jnp.int32),
+                          s((b,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def test_paged_attention_custom_call_name(one_chip, no_compile_cache,
+                                          tpu_ops):
+    """The benchmark's trace reduction finds the decode kernel's time by
+    the prefix ``%paged_attention`` of its custom call, which takes the
+    name of the function wrapping the ``pallas_call``: it must hold
+    inside a caller's program too, one kernel per layer."""
+    _, _, pa = tpu_ops
+    H, KV, hd = WIDTHS["olmo-1b"]
+    q, k, v, table, cur = _paged_args(one_chip, H, KV, hd, 1)
+
+    def two_layers(q, k, v, table, cur):
+        for _ in range(2):
+            q = pa.paged_attention(q, k, v, table, cur)
+        return q
+
+    calls = [line.split("=")[0].split()[-1]
+             for line in _compiled_text(two_layers, q, k, v, table,
+                                        cur).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    assert all(c.startswith("%paged_attention") for c in calls), calls
 
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))

@@ -70,7 +70,7 @@ def test_counters_survive_reset_stats_and_a_new_run(smollm):
     sched.submit(_prompt(cfg, 33), max_new=4)
     sched.run_until_drained()
     first = sched.counters()
-    assert first == {"chunk_iters": 3, "chunk_rows": 3}
+    assert _chunk(first) == {"chunk_iters": 3, "chunk_rows": 3}
     sched.reset_stats()
     assert sched.total_steps == 0 and int(sched.pool.steps) == 0
     assert sched.counters() == first
@@ -79,7 +79,61 @@ def test_counters_survive_reset_stats_and_a_new_run(smollm):
     sched.submit(_prompt(cfg, 17, 1), max_new=4)
     assert sched.counters() == first
     sched.run_until_drained()
-    assert sched.counters() == {"chunk_iters": 5, "chunk_rows": 5}
+    assert _chunk(sched.counters()) == {"chunk_iters": 5, "chunk_rows": 5}
+
+
+def _chunk(c):
+    return {k: c[k] for k in ("chunk_iters", "chunk_rows")}
+
+
+# ----------------------- in-graph decode counters --------------------------
+
+def _decode_recount(sched):
+    """(decode iterations, Σ live pages) of one one-iteration segment,
+    recounted on the host from the pool it leaves: a row decoded if it
+    is still running or retired in it, and a running row's ``cur_len``
+    grew by one there."""
+    p = sched.pool
+    active, done = np.asarray(p.active), np.asarray(p.done)
+    cur = np.asarray(p.cur_len) - active
+    if not (active | done).any():
+        return 0, 0
+    return 1, int(np.sum(-(-cur // BLOCK)))
+
+
+def test_decode_counters_match_a_host_recount(smollm):
+    cfg, _ = smollm
+    sched = _sched(smollm, 2)
+    for i, n in enumerate([20, 40, 9]):
+        sched.submit(_prompt(cfg, n, i), max_new=4 + i)
+    want = np.zeros(2, np.int64)
+    while sched.pending:
+        sched.step(max_steps=1)
+        want += _decode_recount(sched)
+        c = sched.counters()
+        assert (c["decode_iters"], c["decode_pages"]) == tuple(want)
+    # three requests of 4-6 tokens over two slots, 2-7 pages a row
+    assert 6 <= want[0] < want[1]
+    # lifetime: reset_stats leaves both, and a new run adds to them
+    before = sched.counters()
+    sched.reset_stats()
+    assert sched.counters() == before
+    sched.submit(_prompt(cfg, 17, 3), max_new=4)
+    sched.run_until_drained()
+    after = sched.counters()
+    assert after["decode_iters"] > before["decode_iters"]
+    assert after["decode_pages"] > before["decode_pages"]
+
+
+def test_decode_pages_zero_for_a_dense_cache(smollm):
+    cfg, params = smollm
+    sched = sched_lib.DecodeScheduler(
+        params, cfg, n_slots=2, prompt_len=PROMPT_LEN, max_new_cap=8,
+        eos_id=-1, kv="dense", prefill="chunked", chunk_tokens=CHUNK)
+    sched.submit(_prompt(cfg, 20), max_new=4)
+    sched.run_until_drained()
+    c = sched.counters()
+    assert c["decode_iters"] == 4 and c["decode_pages"] == 0
 
 
 # ----------------------- SLO layer: lock wait and stamps -------------------

@@ -39,6 +39,8 @@ from loader import family, load_module  # noqa: E402
 
 TRACE_DIR = ROOT / "bench_out" / "trace"
 SAMPLE = 8                 # finished requests the reference re-reads
+# the SLO layer's host counters of its submit lock, read at the edges
+SUBMIT_LOCK = ("submit_lock_wait_s", "submits")
 
 
 class NoChip(RuntimeError):
@@ -147,7 +149,10 @@ class CompileCounter:
 def counted_slo(slo_lib):
     """``SLOScheduler`` that logs the inner scheduler's counters after
     every round and folds them in before each ``reset_stats()`` (which
-    zeroes them whenever a drained scheduler takes new work)."""
+    zeroes them whenever a drained scheduler takes new work). With
+    ``read_slot_steps`` (traced runs) it also logs the program's own
+    lifetime counters, ``inner.counters()``, once at its start and after
+    every round, where the scheduler has them."""
     import jax
 
     class Counted(slo_lib.SLOScheduler):
@@ -156,6 +161,9 @@ def counted_slo(slo_lib):
             self.read_slot_steps = read_slot_steps
             self.folded = np.zeros(3, np.int64)
             self.log: List[tuple] = []
+            self.program_log: Optional[List[tuple]] = (
+                [] if read_slot_steps and hasattr(inner, "counters")
+                else None)
             self.stepping = False
             reset = inner.reset_stats
 
@@ -163,6 +171,7 @@ def counted_slo(slo_lib):
                 self.folded += self._raw()
                 reset()
             inner.reset_stats = fold_then_reset
+            self._log_program()
 
         def _raw(self):
             inner = self.inner
@@ -170,6 +179,11 @@ def counted_slo(slo_lib):
                     else 0)
             return np.array([inner.total_steps, inner.prefix_hit_blocks,
                              slot], np.int64)
+
+        def _log_program(self):
+            if self.program_log is not None:
+                self.program_log.append((time.perf_counter(),
+                                         self.inner.counters()))
 
         def counters(self) -> np.ndarray:
             """(loop iterations, prefix-hit blocks, busy slot-steps)."""
@@ -181,11 +195,30 @@ def counted_slo(slo_lib):
                 with jax.profiler.TraceAnnotation("bench.slo_step"):
                     events = super().step()
                 self.log.append((time.perf_counter(), self.counters()))
+                self._log_program()
             finally:
                 self.stepping = False
             return events
 
     return Counted
+
+
+def host_reading(obj, *names) -> Optional[dict]:
+    """``{name: obj.name}``, or None where ``obj`` lacks one of them (a
+    program from before it had them)."""
+    if not all(hasattr(obj, n) for n in names):
+        return None
+    return {n: getattr(obj, n) for n in names}
+
+
+def blocks_per_row(sched) -> Optional[int]:
+    """Width of the paged KV cache's block table, None for a program or
+    cache without one."""
+    cache = getattr(getattr(sched, "pool", None), "cache", None)
+    for entry in (cache.values() if isinstance(cache, dict) else [cache]):
+        if hasattr(entry, "blocks_per_row"):
+            return int(entry.blocks_per_row)
+    return None
 
 
 @dataclasses.dataclass
@@ -233,11 +266,13 @@ async def _serve(front, slo, sched_obj, mix, counter, trace: bool):
     w1 = w0 + sched_obj.window_s
     counter.on = True
     started = {"trace": None}
+    lock = []                  # the SLO layer's submit-lock readings
 
     async def window_edges():
         await asyncio.sleep(max(0.0, w0 - time.perf_counter()))
         counter.at_window = counter.n
         if trace:
+            lock.append(host_reading(slo, *SUBMIT_LOCK))
             import jax
             jax.profiler.start_trace(str(TRACE_DIR))
             started["trace"] = jax.profiler.TraceAnnotation("bench.window")
@@ -260,6 +295,8 @@ async def _serve(front, slo, sched_obj, mix, counter, trace: bool):
     await asyncio.sleep(max(0.0, w1 - time.perf_counter()))
     await edge
     counter.on = False
+    if trace:
+        lock.append(host_reading(slo, *SUBMIT_LOCK))
     if trace and started["trace"]:
         import jax
         started["trace"].__exit__(None, None, None)
@@ -269,7 +306,8 @@ async def _serve(front, slo, sched_obj, mix, counter, trace: bool):
     await asyncio.gather(*tasks, return_exceptions=True)
     while front.inflight or slo.stepping:      # let the engine park
         await asyncio.sleep(0.01)
-    return clients, late, (t0, w0, w1)
+    edges = lock if len(lock) == 2 and None not in lock else None
+    return clients, late, (t0, w0, w1), edges
 
 
 # ---------------- the run ----------------------------------------------
@@ -359,12 +397,16 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
 
     if trace:
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
-    clients, late, (t0, w0, w1) = asyncio.run(
+    clients, late, (t0, w0, w1), lock_edges = asyncio.run(
         _serve(front, slo, sched_obj, mix, counter, trace))
     setup_s = w0 - t_start
     mem_peak = int((dev.memory_stats() or {}).get("peak_bytes_in_use", 0))
     log = list(slo.log)
+    program_log = slo.program_log
+    stamps = (slo.request_stamps()
+              if trace and hasattr(slo, "request_stamps") else None)
     cache_bytes = sched.cache_bytes()
+    bpr = blocks_per_row(sched)
     del front, slo, sched, sched_obj
     gc.collect()
 
@@ -381,6 +423,9 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
         "cell": cell_name, "config": c, "mix": mix, "peak": peak,
         "window": (w0, w1), "seconds": w1 - w0, "setup_s": setup_s,
         "clients": list(clients.values()), "counter_log": log,
+        "program_counter_log": program_log, "submit_lock": lock_edges,
+        "request_stamps": stamps, "blocks_per_row": bpr,
+        "monotonic_offset": time.perf_counter() - time.monotonic(),
         "n_slots": s["slots"], "kv_block": s["kv_block"],
         "chunk_tokens": s["chunk_tokens"], "trace": None,
     }
@@ -389,7 +434,9 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
         t = time.perf_counter()
         record["trace"] = trace_reduce.reduce_dir(TRACE_DIR, (w0, w1))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
-        say(f"trace reduced in {time.perf_counter() - t:.3f} s")
+        say(f"trace reduced in {time.perf_counter() - t:.3f} s; share of "
+            f"op time with an op_name path "
+            f"{record['trace']['scope_mapped_share']}")
 
     st = run_stats(record)
     summarize(st)

@@ -149,3 +149,32 @@ def kernel_share(record, kernel: str, work) -> Optional[float]:
         return None
     share, _ = flops.roofline_share(fl, by, secs, record["peak"])
     return share
+
+
+def program_counter_delta(record, name: str) -> Optional[int]:
+    """The program's own lifetime counter ``name`` (a key of
+    ``DecodeScheduler.counters()``, logged after every round of a traced
+    run) over the window: its value at the last round before the
+    window's end less that at the last reading before its start, modulo
+    2**32, since the counters are int32 on the device. None where the
+    run logged no such counter."""
+    w0, w1 = record["window"]
+    log = [(t, c[name]) for t, c in record.get("program_counter_log") or []
+           if name in c]
+    before = [v for t, v in log if t <= w0]
+    upto = [v for t, v in log if t <= w1]
+    if not before or not upto:
+        return None
+    return (upto[-1] - before[-1]) % 2**32
+
+
+def scope_share(record, scope: str) -> Optional[float]:
+    """Share (%) of the device's busy time spent in operations whose
+    ``op_name`` path holds the component ``scope`` (a
+    ``jax.named_scope``, e.g. ``serve.prefill_chunk``). None without a
+    trace or where no operation carries the scope."""
+    tr = record["trace"]
+    if tr is None or tr["busy_s"] <= 0:
+        return None
+    secs = tr.get("scope_busy_s", {}).get(scope)
+    return None if secs is None else 100.0 * secs / tr["busy_s"]

@@ -149,3 +149,102 @@ def test_a_metric_without_a_reader_raises():
 def bench_paths_read(name, r):
     import harness
     return harness.metric_reader(name).read(r)
+
+
+def counters_log(*rows):
+    """Program counter readings (t, {name: value}) as a traced run logs
+    them; values are int32, as the device holds them."""
+    return [(t, dict(c)) for t, c in rows]
+
+
+def test_program_counter_delta_across_the_window_and_its_wrap():
+    log = counters_log(
+        (5.0, {"c": 2**31 - 10}), (9.5, {"c": 2**31 - 4}),
+        (15.0, {"c": -2**31 + 2}), (19.0, {"c": -2**31 + 6}),
+        (21.0, {"c": -2**31 + 9}))
+    r = record([], program_counter_log=log)
+    # from 2**31 - 4 to 2**31 + 6 as int32 wrapped: 10
+    assert mc.program_counter_delta(r, "c") == 10
+    assert mc.program_counter_delta(r, "absent") is None
+    assert mc.program_counter_delta(record([]), "c") is None
+    late = record([], program_counter_log=counters_log((12.0, {"c": 3})))
+    assert mc.program_counter_delta(late, "c") is None
+
+
+def chunk_log(iters, rows, d_iters, pages):
+    return counters_log(
+        (9.0, {"chunk_iters": 100, "chunk_rows": 300, "decode_iters": 50,
+               "decode_pages": 1000}),
+        (20.0, {"chunk_iters": 100 + iters, "chunk_rows": 300 + rows,
+                "decode_iters": 50 + d_iters,
+                "decode_pages": 1000 + pages}))
+
+
+def test_prefill_row_share_by_hand():
+    # 10 chunk iterations over 4 slots, 6 prefilling rows among them
+    r = record([], program_counter_log=chunk_log(10, 6, 0, 0))
+    assert bench_paths_read("prefill_row_share.serve", r) == \
+        pytest.approx(100 * 6 / 40)
+
+
+def test_decode_visited_share_by_hand():
+    # 20 decode iterations, 4 slots, 8 pages per row: 640 in the grid
+    r = record([], program_counter_log=chunk_log(0, 0, 20, 160),
+               blocks_per_row=8)
+    assert bench_paths_read("decode_visited_share.batch", r) == \
+        pytest.approx(25.0)
+
+
+def test_submit_blocked_share_by_hand():
+    r = record([], submit_lock=[
+        {"submit_lock_wait_s": 1.5, "submits": 3},
+        {"submit_lock_wait_s": 9.0, "submits": 40}])
+    assert bench_paths_read("submit_blocked_share.serve", r) == \
+        pytest.approx(75.0)
+
+
+def test_prefill_device_share_by_hand():
+    r = record([], trace={"busy_s": 8.0, "window_s": 10.0, "kernels": {},
+                          "scope_busy_s": {"serve.prefill_chunk": 6.0,
+                                           "serve.decode": 1.5}})
+    assert bench_paths_read("prefill_device_share.serve", r) == \
+        pytest.approx(75.0)
+    assert mc.scope_share(r, "serve.decode") == pytest.approx(18.75)
+    assert mc.scope_share(r, "moe") is None
+
+
+def test_queue_wait_counts_a_request_not_admitted_at_its_wait_so_far():
+    """Stamps on the program's clock, 100 s behind the benchmark's: a
+    request admitted 2 s after its submission, one not admitted by the
+    window's end (20 on the benchmark's clock), and one due before the
+    window, left out."""
+    cls = [C(11.0, 10, []), C(12.0, 10, []), C(5.0, 10, [])]
+    for rid, cl in enumerate(cls):
+        cl.rid = rid
+    stamps = [
+        {"request_id": 0, "arrival": -89.0, "admitted": -87.0},
+        {"request_id": 1, "arrival": -88.0, "admitted": None},
+        {"request_id": 2, "arrival": -95.0, "admitted": -70.0}]
+    r = record(cls, request_stamps=stamps, monotonic_offset=100.0)
+    assert bench_paths_read("queue_wait_p95", r) == pytest.approx(
+        float(np.percentile([2.0, 8.0], 95)))
+
+
+@pytest.mark.parametrize("name", [
+    "prefill_device_share.serve", "prefill_row_share.batch",
+    "decode_visited_share.serve", "submit_blocked_share.serve",
+    "queue_wait_p95"])
+def test_new_readers_are_silent_without_their_data(name):
+    """A run of a program from before the counters, spans and stamps
+    existed, or an untraced run: every new reader gives None."""
+    r = record([], trace={"busy_s": 1.0, "window_s": 10.0, "kernels": {}},
+               program_counter_log=None, submit_lock=None,
+               request_stamps=None, blocks_per_row=None)
+    assert bench_paths_read(name, r) is None
+    assert bench_paths_read(name, record([])) is None
+
+
+def test_decode_visited_share_needs_the_table_width():
+    r = record([], program_counter_log=chunk_log(0, 0, 20, 160),
+               blocks_per_row=None)
+    assert bench_paths_read("decode_visited_share.serve", r) is None

@@ -102,6 +102,91 @@ def test_counters_accumulate_across_resets(sound_run):
     assert it == sorted(it) and it[-1] > 0
 
 
+@pytest.fixture(scope="module")
+def traced_run(tmp_path_factory):
+    """A traced run: the profiler records, and its ``.xplane.pb`` is
+    read; the rest of the reduction (which wants a TPU's device plane)
+    is stood in for. The program's ``counters()`` carries one key more
+    than it has, as a later program's might: ``probe``, the number of
+    times it was read."""
+    import harness
+    import trace_reduce
+    from repro.serve import scheduler
+    seen = {}
+    reads = [0]
+    orig = scheduler.DecodeScheduler.counters
+
+    def counters(self):
+        reads[0] += 1
+        return dict(orig(self), probe=reads[0])
+
+    def reduce_dir(trace_dir, window):
+        files = sorted(trace_dir.glob("**/*.xplane.pb"))
+        seen["ev"] = trace_reduce.read_xspace(files[-1].read_bytes())
+        return {"busy_s": 1.0, "window_s": window[1] - window[0],
+                "kernels": {}, "scope_busy_s": {},
+                "scope_mapped_share": None,
+                "breakdown": {"device_ops": [], "idle_gaps": [],
+                              "idle_by_span": []}}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(scheduler.DecodeScheduler, "counters", counters)
+        mp.setattr(trace_reduce, "reduce_dir", reduce_dir)
+        mp.setattr(harness, "TRACE_DIR",
+                   tmp_path_factory.mktemp("bench_out") / "trace")
+        out, rec = harness.run_cell(
+            "olmo-1b.chat", SEED, 4.0, True, t_start=time.perf_counter(),
+            rehearsal=smoke_cell())
+    return out, rec, seen["ev"]
+
+
+def test_a_traced_run_logs_the_program_counters_each_round(traced_run):
+    _, rec, _ = traced_run
+    log = rec["program_counter_log"]
+    assert len(log) == len(rec["counter_log"]) + 1    # and one at start
+    assert all(set(c) == {"chunk_iters", "chunk_rows", "decode_iters",
+                          "decode_pages", "probe"} for _, c in log)
+    assert [c["probe"] for _, c in log] == list(range(1, len(log) + 1))
+    assert rec["blocks_per_row"] == -(-(60 + 32) // 16)
+
+
+def test_an_untraced_run_logs_no_program_reading(sound_run):
+    _, rec, _ = sound_run
+    assert rec["program_counter_log"] is None
+    assert rec["submit_lock"] is None and rec["request_stamps"] is None
+
+
+def test_a_new_counter_reaches_the_window_delta(traced_run):
+    """``probe`` is in no bench file: its delta over the window is the
+    number of rounds logged inside the window."""
+    import metrics_common as mc
+    _, rec, _ = traced_run
+    w0, w1 = rec["window"]
+    times = [t for t, _ in rec["program_counter_log"]]
+    inside = sum(1 for t in times if w0 < t <= w1)
+    assert inside > 0
+    assert mc.program_counter_delta(rec, "probe") == inside
+    assert mc.program_counter_delta(rec, "no_such_counter") is None
+
+
+def test_a_traced_run_reports_the_program_readings(traced_run):
+    out, rec, ev = traced_run
+    assert out["correct"] is True
+    for name in ("prefill_row_share.serve", "decode_visited_share.serve",
+                 "submit_blocked_share.serve", "queue_wait_p95"):
+        assert name in out["metrics"], name
+    assert 0 < out["metrics"]["prefill_row_share.serve"]["value"] <= 100
+    assert 0 < out["metrics"]["decode_visited_share.serve"]["value"] <= 100
+    assert 0 <= out["metrics"]["submit_blocked_share.serve"]["value"]
+    lock = rec["submit_lock"]
+    assert lock[1]["submits"] > lock[0]["submits"]
+    rids = {cl.rid for cl in rec["clients"]}
+    assert rec["request_stamps"] and all(
+        s["request_id"] in rids for s in rec["request_stamps"])
+    # the program's own host spans reach the reduction's input
+    names = {p[0] for p in ev["program"]}
+    assert {"serve.round", "serve.submit"} <= names
+
+
 def test_counted_slo_folds_counters_before_each_reset():
     """Two runs on a scheduler that drains between them: the inner
     counters restart, the harness's fold keeps both runs' iterations."""

@@ -25,7 +25,54 @@ class MoEConfig:
     d_ff_expert: int
     n_shared_experts: int = 0
     d_ff_shared: int = 0          # total hidden width of fused shared experts
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # "capacity" dispatch only
+    # dispatch: "capacity" (GShard grouping; tokens past the capacity
+    # drop — the training path) | "dropless" (serving: every routed
+    # entry on an expert held here is computed, none is dropped)
+    dispatch: str = "capacity"
+    # router: "softmax" (top-k of the softmax, renormalised) |
+    # "sigmoid" (DeepSeek-V3 noaux_tc with one group: top-k of
+    # sigmoid + e_score_correction_bias, weights from the sigmoid)
+    router: str = "softmax"
+    norm_topk: bool = True         # renormalise the k chosen weights
+    routed_scale: float = 1.0      # routed_scaling_factor
+    # expert parallelism: the router spans ``n_router`` experts (0 =
+    # n_experts); this layer holds ``n_experts`` of them, starting at
+    # ``expert_offset``, and computes only their part of the result
+    n_router: int = 0
+    expert_offset: int = 0
+
+    @property
+    def router_experts(self) -> int:
+        return self.n_router or self.n_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3), no q-LoRA: K and V
+    of every head come from one cached latent row, ``c`` (RMS-normed
+    ``kv_lora_rank`` values) and one shared RoPE key of
+    ``qk_rope_head_dim``."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    kv_norm_eps: float = 1e-6      # the latent's RMSNorm (library default)
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def row(self) -> int:
+        """Values of one token's cached latent row: ``c`` then k_pe."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def row_lanes(self) -> int:
+        """The row padded to whole 128-lane tiles, as the pool stores it
+        (the TPU lays the minor dim out in 128-lane tiles anyway)."""
+        return -(-self.row // 128) * 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,7 +102,13 @@ class ModelConfig:
     norm: str = "rmsnorm"          # rmsnorm|layernorm|nonparametric_ln
     tie_embeddings: bool = False
     rope_theta: float = 10000.0
+    rope_interleave: bool = False  # RoPE pairs (2i, 2i+1), not (i, i+d/2)
+    norm_eps: float = 0.0          # 0: the norm's own default
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None
+    # leading layers whose FFN is a dense SwiGLU of width d_ff ahead of
+    # the MoE stack (DeepSeek's first_k_dense_replace)
+    first_k_dense: int = 0
     ssm: Optional[SSMConfig] = None
     # hybrid (zamba2): one shared attn+MLP block applied every k layers
     shared_attn_every: int = 0
@@ -159,6 +212,8 @@ _MODULES = {
     "llama3.2-1b": "llama3p2_1b",
     "whisper-small": "whisper_small",
     "internvl2-1b": "internvl2_1b",
+    # served (bench/), outside the assigned dry-run pool
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 

@@ -27,7 +27,9 @@ Layout/behaviour contract (shared with ``ref.py`` and
   last visible block in the index map, so the sequential-grid pipeline
   elides their DMAs, and ``pl.when`` skips their FLOPs;
 - the online-softmax accumulator lives in VMEM scratch across the
-  innermost (sequential) block axis.
+  innermost (sequential) block axis;
+- a latent pool (``v_pool=None``) is its own value, as in
+  ``kernels.paged_attention``: each block is fetched once.
 
 Grid: ``(B, KV, nb)``; nb = blocks_per_row, innermost/sequential.
 VMEM per step: q (C·G·hd) + k/v (block·hd) + acc (C·G·hd fp32) +
@@ -49,10 +51,13 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _fp_kernel(table_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
-               acc_ref, m_ref, l_ref, *, block: int, nb: int, C: int,
-               G: int, scale: float):
+def _fp_kernel(table_ref, off_ref, q_ref, *refs, block: int, nb: int,
+               C: int, G: int, scale: float, latent: bool):
     """Grid: (B, KV, nb); nb innermost/sequential."""
+    if latent:
+        k_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    else:
+        k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
     b, j = pl.program_id(0), pl.program_id(2)
 
     @pl.when(j == 0)
@@ -67,7 +72,7 @@ def _fp_kernel(table_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale        # (R, hd)
         k = k_ref[0, 0].astype(jnp.float32)                # (block, hd)
-        v = v_ref[0, 0].astype(jnp.float32)
+        v = k if latent else v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         qpos = off + jax.lax.broadcasted_iota(jnp.int32, (R, block), 0) // G
@@ -94,14 +99,16 @@ def _fp_kernel(table_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def flash_prefill(q, k_pool, v_pool, table, q_off, *,
-                  interpret: bool = True):
-    """q: (B, C, H, hd); k/v_pool: (n_blocks, KV, block, hd);
-    table: (B, bpr) int32; q_off: (B,) int32 -> (B, C, H, hd)."""
+                  interpret: bool = True, scale=None):
+    """q: (B, C, H, hd); k/v_pool: (n_blocks, KV, block, hd), v_pool
+    None for a latent pool; table: (B, bpr) int32; q_off: (B,) int32
+    -> (B, C, H, hd). ``scale`` defaults to ``1/sqrt(hd)``."""
     B, C, H, hd = q.shape
     KV, block = k_pool.shape[1], k_pool.shape[2]
     G = H // KV
     bpr = table.shape[1]
-    scale = 1.0 / math.sqrt(hd)
+    latent = v_pool is None
+    scale = 1.0 / math.sqrt(hd) if scale is None else float(scale)
     # (B, C, KV, G, hd) -> (B, KV, C*G, hd): one (chunk x group) tile
     # per KV head, query rows c-major so row r is (c = r // G, g = r % G)
     qg = q.reshape(B, C, KV, G, hd).transpose(0, 2, 1, 3, 4) \
@@ -116,15 +123,14 @@ def flash_prefill(q, k_pool, v_pool, table, q_off, *,
         jj = jnp.minimum(j, last)
         return (jnp.maximum(table_ref[b, jj], 0), h, 0, 0)
 
+    pools = (k_pool,) if latent else (k_pool, v_pool)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, KV, bpr),
         in_specs=[
             pl.BlockSpec((1, 1, C * G, hd),
                          lambda b, h, j, t, c: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block, hd), kv_map),
-            pl.BlockSpec((1, 1, block, hd), kv_map),
-        ],
+        ] + [pl.BlockSpec((1, 1, block, hd), kv_map)] * len(pools),
         out_specs=pl.BlockSpec((1, 1, C * G, hd),
                                lambda b, h, j, t, c: (b, h, 0, 0)),
         scratch_shapes=[
@@ -134,11 +140,11 @@ def flash_prefill(q, k_pool, v_pool, table, q_off, *,
         ],
     )
     kern = functools.partial(_fp_kernel, block=block, nb=bpr, C=C, G=G,
-                             scale=scale)
+                             scale=scale, latent=latent)
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, C * G, hd), q.dtype),
         interpret=interpret,
-    )(table, q_off, qg, k_pool, v_pool)
+    )(table, q_off, qg, *pools)
     return out.reshape(B, KV, C, G, hd).transpose(0, 2, 1, 3, 4) \
         .reshape(B, C, H, hd)

@@ -12,10 +12,11 @@ from .kernel import flash_prefill as _kernel
 from .ref import flash_prefill_ref
 
 
-@jax.jit
-def flash_prefill(q, k_pool, v_pool, table, q_off):
-    """Dispatch: compiled Pallas on TPU, interpret-mode elsewhere."""
-    kern = functools.partial(_kernel, interpret=not on_tpu())
+@functools.partial(jax.jit, static_argnames=("scale",))
+def flash_prefill(q, k_pool, v_pool, table, q_off, scale=None):
+    """Dispatch: compiled Pallas on TPU, interpret-mode elsewhere.
+    ``v_pool`` None: a latent pool, its own value."""
+    kern = functools.partial(_kernel, interpret=not on_tpu(), scale=scale)
     return per_device(kern, rows=(0, 3, 4))(q, k_pool, v_pool, table,
                                             q_off)
 

@@ -41,7 +41,11 @@ Layout/behaviour contract (shared with ``ref.py`` and
 - the math is ``models.attention.decode_attention``'s: K/V read in
   the pool's dtype and upcast, fp32 scores, fp32 softmax statistics,
   fp32 ``p`` in the PV product and an fp32 accumulator (online softmax
-  per KV head across chunks), cast to ``q.dtype`` at the end.
+  per KV head across chunks), cast to ``q.dtype`` at the end;
+- a latent pool (``v_pool=None``, latent attention's rows as one KV
+  head) is its own value: each page is fetched once and ``p`` is
+  multiplied by the K rows themselves, so the output holds all ``hd``
+  lanes of the weighted rows (the caller takes the value lanes).
 """
 
 from __future__ import annotations
@@ -67,10 +71,14 @@ def pages_per_chunk(bpr: int, KV: int, block: int, hd: int,
     return max(1, min(bpr, CHUNK_BYTES // page))
 
 
-def _pa_kernel(table_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref,
-               k_buf, v_buf, sem, acc_ref, m_ref, l_ref, *,
-               block: int, P: int, scale: float):
+def _pa_kernel(table_ref, cl_ref, q_ref, *refs, block: int, P: int,
+               scale: float, latent: bool):
     """Grid: (B,). One row, all its KV heads, its live pages only."""
+    if latent:
+        k_hbm, o_ref, k_buf, sem, acc_ref, m_ref, l_ref = refs
+    else:
+        (k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, acc_ref, m_ref,
+         l_ref) = refs
     b = pl.program_id(0)
     KV, hd = k_buf.shape[2], k_buf.shape[4]
     # a cur_len past the table's capacity reads every page once, as the
@@ -83,10 +91,13 @@ def _pa_kernel(table_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref,
     def page_copies(slot, i, c=0):
         # page i of chunk c: padding past the last live page re-reads it
         page = jnp.maximum(table_ref[b, jnp.minimum(c * P + i, last)], 0)
-        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot, i],
-                                      sem.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, i],
-                                      sem.at[1, slot]))
+        k_cp = pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot, i],
+                                     sem.at[0, slot])
+        if latent:
+            return (k_cp,)
+        return (k_cp, pltpu.make_async_copy(v_hbm.at[page],
+                                            v_buf.at[slot, i],
+                                            sem.at[1, slot]))
 
     def start(c, slot):
         def one(i, carry):
@@ -125,7 +136,8 @@ def _pa_kernel(table_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref,
         for h in range(KV):
             q = q_ref[0, h].astype(jnp.float32) * scale    # (G, hd)
             k = k_buf[slot, :, h].reshape(P * block, hd).astype(jnp.float32)
-            v = v_buf[slot, :, h].reshape(P * block, hd).astype(jnp.float32)
+            v = k if latent else v_buf[slot, :, h].reshape(
+                P * block, hd).astype(jnp.float32)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             s = jnp.where(live, s, NEG_INF)
@@ -146,14 +158,16 @@ def _pa_kernel(table_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 def paged_attention(q, k_pool, v_pool, table, cur_len, *,
-                    interpret: bool = True):
-    """q: (B, 1, H, hd); k/v_pool: (n_blocks, KV, block, hd);
-    table: (B, bpr) int32; cur_len: (B,) int32 -> (B, 1, H, hd)."""
+                    interpret: bool = True, scale=None):
+    """q: (B, 1, H, hd); k/v_pool: (n_blocks, KV, block, hd), v_pool
+    None for a latent pool; table: (B, bpr) int32; cur_len: (B,) int32
+    -> (B, 1, H, hd). ``scale`` defaults to ``1/sqrt(hd)``."""
     B, _, H, hd = q.shape
     KV, block = k_pool.shape[1], k_pool.shape[2]
     G = H // KV
     bpr = table.shape[1]
-    scale = 1.0 / math.sqrt(hd)
+    latent = v_pool is None
+    scale = 1.0 / math.sqrt(hd) if scale is None else float(scale)
     # The TPU compiler slices an HBM array only in whole 128-lane tiles,
     # so a narrower head is zero-padded to the lane width (a copy of the
     # layer's pool per call; exact: the zero lanes add nothing to a
@@ -161,31 +175,33 @@ def paged_attention(q, k_pool, v_pool, table, cur_len, *,
     lanes = -(-hd // 128) * 128
     if lanes != hd:
         pad = [(0, 0)] * 3 + [(0, lanes - hd)]
-        q, k_pool, v_pool = (jnp.pad(x, pad) for x in (q, k_pool, v_pool))
+        q, k_pool = jnp.pad(q, pad), jnp.pad(k_pool, pad)
+        if not latent:
+            v_pool = jnp.pad(v_pool, pad)
     P = pages_per_chunk(bpr, KV, block, lanes, k_pool.dtype.itemsize)
     qg = q.reshape(B, KV, G, lanes)
     table = jnp.asarray(table, jnp.int32)
     cur_len = jnp.asarray(cur_len, jnp.int32)
     row = pl.BlockSpec((1, KV, G, lanes), lambda b, t, c: (b, 0, 0, 0))
+    pools = (k_pool,) if latent else (k_pool, v_pool)
+    bufs = [pltpu.VMEM((2, P, KV, block, lanes), x.dtype) for x in pools]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B,),
-        in_specs=[row, pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=[row] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
         out_specs=row,
-        scratch_shapes=[
-            pltpu.VMEM((2, P, KV, block, lanes), k_pool.dtype),
-            pltpu.VMEM((2, P, KV, block, lanes), v_pool.dtype),
+        scratch_shapes=bufs + [
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((KV, G, lanes), jnp.float32),
             pltpu.VMEM((KV, G, 1), jnp.float32),
             pltpu.VMEM((KV, G, 1), jnp.float32),
         ],
     )
-    kern = functools.partial(_pa_kernel, block=block, P=P, scale=scale)
+    kern = functools.partial(_pa_kernel, block=block, P=P, scale=scale,
+                             latent=latent)
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, lanes), q.dtype),
         interpret=interpret,
-    )(table, cur_len, qg, k_pool, v_pool)
+    )(table, cur_len, qg, *pools)
     return out[..., :hd].reshape(B, 1, H, hd)

@@ -30,9 +30,12 @@ def layer_norm(x, weight=None, bias=None, eps: float = 1e-5):
     return x.astype(dt)
 
 
-def apply_norm(kind: str, x, params, name: str):
-    """kind: rmsnorm | layernorm | nonparametric_ln (OLMo)."""
+def apply_norm(kind: str, x, params, name: str, eps: float = 0.0):
+    """kind: rmsnorm | layernorm | nonparametric_ln (OLMo). ``eps`` 0
+    keeps the norm's own default (``cfg.norm_eps`` where it is set)."""
     if kind == "rmsnorm":
+        if eps:
+            return rms_norm(x, params[name], eps)
         return rms_norm(x, params[name])
     if kind == "layernorm":
         return layer_norm(x, params[name], params.get(name + "_b"))
@@ -52,8 +55,16 @@ def norm_params(b, kind: str, d: int, name: str):
     return out
 
 
-def rope(x, positions, theta: float):
-    """Rotary embedding. x: (..., seq, heads, head_dim); positions (..., seq)."""
+def rope(x, positions, theta: float, interleave: bool = False):
+    """Rotary embedding. x: (..., seq, heads, head_dim); positions (..., seq).
+
+    Pairs (i, i + d/2) are rotated by frequency i; ``interleave`` pairs
+    (2i, 2i + 1) instead (DeepSeek-V3's ``rope_interleave``), with the
+    output laid out de-interleaved, evens then odds, as the published
+    code leaves it (a dot product of two rotated vectors is the same
+    either way)."""
+    if interleave:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
     d = x.shape[-1]
     half = d // 2
     freqs = jnp.exp(

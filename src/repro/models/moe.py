@@ -18,6 +18,16 @@ TPU-native dispatch (GShard-style grouping + sort-based capacity):
   tensor parallelism over the expert FFN dim;
 - everything is reverse-differentiable through the gather/scatter
   transpose pair.
+
+Serving uses the **dropless expert-share** layer (``dropless_moe``,
+``cfg.moe.dispatch == "dropless"``): the router spans all
+``router_experts`` at published width, the layer holds ``n_experts``
+of them from ``expert_offset`` (one chip's share under expert
+parallelism), and the entries routed to them are computed as one
+grouped matmul over entries sorted by expert — no capacity, so no
+token is dropped and pad rows cannot displace real ones. Entries on
+experts held elsewhere add nothing here; the layer stands in for no
+absent chip and no exchange.
 """
 
 from __future__ import annotations
@@ -34,7 +44,8 @@ from . import layers
 def moe_params(b, cfg, d_model: int):
     m = cfg.moe
     p = {
-        "router": b.p((d_model, m.n_experts), (sh.EMBED, None), scale=0.1),
+        "router": b.p((d_model, m.router_experts), (sh.EMBED, None),
+                      scale=0.1),
         "w_gate": b.p((m.n_experts, d_model, m.d_ff_expert),
                       (sh.EXPERT, sh.EMBED, sh.EXPERT_MLP), fan_in=d_model),
         "w_up": b.p((m.n_experts, d_model, m.d_ff_expert),
@@ -43,6 +54,10 @@ def moe_params(b, cfg, d_model: int):
                       (sh.EXPERT, sh.EXPERT_MLP, sh.EMBED),
                       fan_in=m.d_ff_expert),
     }
+    if m.router == "sigmoid":
+        # float32, as published: it only reorders the choice
+        p["e_score_correction_bias"] = b.p(
+            (m.router_experts,), (None,), init="zeros", dtype=jnp.float32)
     if m.n_shared_experts:
         p["shared_gate"] = b.p((d_model, m.d_ff_shared), (sh.EMBED, sh.MLP))
         p["shared_up"] = b.p((d_model, m.d_ff_shared), (sh.EMBED, sh.MLP))
@@ -226,3 +241,78 @@ def moe_mlp(p: Dict, x: jax.Array, cfg, rules=None
     if regrouped:
         out = out.reshape(B0, S0, D0)
     return out, aux
+
+
+# =========================== dropless expert share ==========================
+
+def route(p: Dict, x: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
+    """Routing over all ``router_experts``, in float32: x (T, D) ->
+    (weights (T, K) float32, experts (T, K) int32).
+
+    ``router="sigmoid"`` is DeepSeek-V3's noaux_tc with one group:
+    scores ``s = sigmoid(x W_r)``, the top k chosen by
+    ``s + e_score_correction_bias``, weighted by ``s``; ``"softmax"``
+    chooses and weighs by the softmax. The k weights are renormalised
+    (``norm_topk``) and scaled by ``routed_scale``."""
+    m = cfg.moe
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                        p["router"].astype(jnp.float32))
+    if m.router == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(
+            s + p["e_score_correction_bias"].astype(jnp.float32), m.top_k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+    else:
+        w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), m.top_k)
+    if m.norm_topk:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return w * m.routed_scale, idx
+
+
+def dropless_moe(p: Dict, x: jax.Array, cfg, valid=None
+                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """x: (B, S, D) -> (out, counts). The part of the layer's output the
+    experts held here give, plus the shared experts (computed once, as
+    every chip computes them alike).
+
+    ``valid`` (B, S) bool marks real tokens; the entries of the others
+    (masked slots, pad lanes past a prompt's end, whose outputs the
+    caller discards) are routed but not computed. ``counts``:
+    ``moe_routed`` (entries of real tokens) and ``moe_local`` (those
+    on the experts held here), int32."""
+    from ..kernels.grouped_matmul.ops import TM, expert_gmm
+    m = cfg.moe
+    B, S, D = x.shape
+    T, K, E = B * S, m.top_k, m.n_experts
+    cdt = cfg.dtype("compute")
+    with jax.named_scope("serve.moe"):
+        xf = x.reshape(T, D).astype(cdt)
+        w, idx = route(p, xf, cfg)
+        real = (jnp.ones((T,), bool) if valid is None
+                else jnp.asarray(valid, bool).reshape(T))
+        e = idx - m.expert_offset
+        local = (e >= 0) & (e < E) & real[:, None]
+        # entries sorted by held expert; the rest (E) after them
+        key = jnp.where(local, e, E).reshape(-1)               # (T*K,)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.sum(key[:, None] == jnp.arange(E)[None, :],
+                        axis=0).astype(jnp.int32)
+        Mp = -(-T * K // TM) * TM
+        xs = xf[jnp.pad(order // K, (0, Mp - T * K))]         # (Mp, D)
+        g = expert_gmm(xs, p["w_gate"].astype(cdt), sizes)
+        u = expert_gmm(xs, p["w_up"].astype(cdt), sizes)
+        # rows past the local entries come back as zeros, and so do
+        # their weights
+        y = expert_gmm(jax.nn.silu(g) * u, p["w_down"].astype(cdt),
+                       sizes)[:T * K]
+        ws = jnp.where(local.reshape(-1), w.reshape(-1), 0.0)[order]
+        y = y.astype(jnp.float32) * ws[:, None]
+        inv = jnp.zeros((T * K,), jnp.int32).at[order].set(
+            jnp.arange(T * K, dtype=jnp.int32))
+        out = y[inv].reshape(T, K, D).sum(axis=1)
+        if m.n_shared_experts:
+            out = out + layers.swiglu(xf, p["shared_gate"], p["shared_up"],
+                                      p["shared_down"], cdt)
+    counts = {"moe_routed": (jnp.sum(real) * K).astype(jnp.int32),
+              "moe_local": jnp.sum(sizes)}
+    return out.astype(x.dtype).reshape(B, S, D), counts

@@ -27,17 +27,21 @@ class Builder:
         return jax.random.fold_in(self._key, self._count)
 
     def p(self, shape: Sequence[int], axes: Tuple, *,
-          init: str = "fan_in", scale: float = 1.0, fan_in: int = 0):
+          init: str = "fan_in", scale: float = 1.0, fan_in: int = 0,
+          dtype=None):
+        """``dtype`` overrides the model's parameter dtype for this leaf
+        (a leaf published in a wider type than the rest)."""
         shape = tuple(int(s) for s in shape)
         assert len(axes) == len(shape), (shape, axes)
+        dt = self.dtype if dtype is None else dtype
         if self.mode == "axes":
             return tuple(axes)
         if self.mode == "abstract":
-            return jax.ShapeDtypeStruct(shape, self.dtype)
+            return jax.ShapeDtypeStruct(shape, dt)
         if init == "zeros":
-            return jnp.zeros(shape, self.dtype)
+            return jnp.zeros(shape, dt)
         if init == "ones":
-            return jnp.ones(shape, self.dtype)
+            return jnp.ones(shape, dt)
         if init == "fan_in":
             fi = fan_in or (shape[-2] if len(shape) >= 2 else shape[-1])
             std = scale / np.sqrt(max(fi, 1))
@@ -45,8 +49,8 @@ class Builder:
             std = scale
         else:
             raise ValueError(init)
-        return (jax.random.normal(self._next_key(), shape, self.dtype)
-                * jnp.asarray(std, self.dtype))
+        return (jax.random.normal(self._next_key(), shape, dt)
+                * jnp.asarray(std, dt))
 
 
 def build_all(build_fn, cfg, key=None, dtype=jnp.float32):

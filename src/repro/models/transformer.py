@@ -28,12 +28,14 @@ from .. import core
 from ..dist import sharding as sh
 from . import adaptive
 from . import attention as attn_lib
-from . import layers, moe as moe_lib, ssm as ssm_lib
+from . import layers, mla as mla_lib, moe as moe_lib, ssm as ssm_lib
 
 
 # =========================== parameters ====================================
 
 def attn_params(b, cfg, d_model: int):
+    if cfg.mla is not None:
+        return mla_lib.mla_params(b, cfg, d_model)
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     p = {
         # fan-in is d_model: the default (second-to-last dim) would
@@ -62,11 +64,13 @@ def mlp_params(b, cfg, d_model: int, d_ff: int):
     }
 
 
-def _attn_block_params(b, cfg):
+def _attn_block_params(b, cfg, ffn: bool = True):
     p = {}
     p.update(layers.norm_params(b, cfg.norm, cfg.d_model, "ln_attn"))
     p.update({"attn": attn_params(b, cfg, cfg.d_model)})
     p.update(layers.norm_params(b, cfg.norm, cfg.d_model, "ln_mlp"))
+    if not ffn:
+        return p
     if cfg.family == "moe":
         p["moe"] = moe_lib.moe_params(b, cfg, cfg.d_model)
     else:
@@ -102,7 +106,15 @@ def build_params(cfg, b):
     p: Dict[str, Any] = {
         "embed": b.p((Vp, D), (sh.VOCAB, sh.EMBED), init="normal", scale=0.02),
     }
-    if cfg.family in ("dense", "moe", "vlm"):
+    if cfg.first_k_dense:
+        # leading dense FFNs apart from the MoE stack (layer_ffn); the
+        # attention blocks of every layer stay one stack, one scan
+        k = cfg.first_k_dense
+        p["layers"] = _attn_block_params(_StackedBuilder(b, L), cfg,
+                                         ffn=False)
+        p["dense_mlp"] = mlp_params(_StackedBuilder(b, k), cfg, D, cfg.d_ff)
+        p["moe"] = moe_lib.moe_params(_StackedBuilder(b, L - k), cfg, D)
+    elif cfg.family in ("dense", "moe", "vlm"):
         p["layers"] = _attn_block_params(_StackedBuilder(b, L), cfg)
     elif cfg.family == "ssm":
         p["layers"] = _ssm_block_params(_StackedBuilder(b, L), cfg)
@@ -145,7 +157,13 @@ def attn_apply(p, x, cfg, rules, *, positions, mode: str = "full",
     lanes there), but attention runs ``verify_attention`` — per-
     position DECODE math, so greedy acceptance stays bitwise equal to
     sequential decode (see ``models.attention.verify_attention``).
+
+    Latent attention (``cfg.mla``) runs ``models.mla.mla_apply``.
     """
+    if cfg.mla is not None:
+        return mla_lib.mla_apply(p, x, cfg, positions=positions, mode=mode,
+                                 kv_cache=kv_cache, cur_len=cur_len,
+                                 chunk_off=chunk_off)
     cdt = cfg.dtype("compute")
     xc = x.astype(cdt)
     q = jnp.einsum("bsd,dhk->bshk", xc, p["wq"].astype(cdt))
@@ -233,17 +251,68 @@ def attn_apply(p, x, cfg, rules, *, positions, mode: str = "full",
     return out, new_kv
 
 
+def dropless(cfg) -> bool:
+    """The MoE layers are the dropless expert share (serving)."""
+    return cfg.moe is not None and cfg.moe.dispatch == "dropless"
+
+
+def zero_counts(cfg) -> Dict[str, jax.Array]:
+    """The per-step counts a layer stack sums (``dropless_moe``'s;
+    none for other models)."""
+    if not dropless(cfg):
+        return {}
+    return {"moe_routed": jnp.int32(0), "moe_local": jnp.int32(0)}
+
+
+def layer_ffn(params, cfg, i, valid=None):
+    """The FFN of layer ``i`` (an int or a traced index) where the model
+    keeps its leading dense FFNs apart from the MoE stack
+    (``cfg.first_k_dense``): ``h -> (out, counts)``, choosing the dense
+    SwiGLU or the MoE layer by ``i`` in the compiled program. None for
+    other models: the block holds its own FFN."""
+    k = cfg.first_k_dense
+    if not k:
+        return None
+    cdt = cfg.dtype("compute")
+
+    def ffn(h):
+        def dense(h):
+            mp = jax.tree.map(lambda a: a[jnp.minimum(i, k - 1)],
+                              params["dense_mlp"])
+            out = layers.swiglu(h, mp["w_gate"], mp["w_up"], mp["w_down"],
+                                cdt)
+            return out.astype(h.dtype), zero_counts(cfg)
+
+        def moe(h):
+            mp = jax.tree.map(lambda a: a[jnp.maximum(i - k, 0)],
+                              params["moe"])
+            out, counts = moe_lib.dropless_moe(mp, h, cfg, valid)
+            return out.astype(h.dtype), counts
+
+        return jax.lax.cond(jnp.asarray(i) < k, dense, moe, h)
+
+    return ffn
+
+
 def attn_block(p, x, cfg, rules, *, positions, mode="full", kv_cache=None,
-               cur_len=None, chunk_off=None):
-    """Pre-norm attention + (MoE|MLP) block. Returns (x, new_kv, aux)."""
-    h = layers.apply_norm(cfg.norm, x, p, "ln_attn")
+               cur_len=None, chunk_off=None, valid=None, ffn=None):
+    """Pre-norm attention + (MoE|MLP) block. Returns (x, new_kv, aux).
+
+    ``ffn`` (``layer_ffn``) replaces the block's own FFN; ``valid``
+    (B, S) marks the real tokens a dropless MoE layer computes (its
+    ``aux`` is then its counts)."""
+    h = layers.apply_norm(cfg.norm, x, p, "ln_attn", cfg.norm_eps)
     a, new_kv = attn_apply(p["attn"], h, cfg, rules, positions=positions,
                            mode=mode, kv_cache=kv_cache, cur_len=cur_len,
                            chunk_off=chunk_off)
     a = checkpoint_name(a, "attn_out")
     x = x + a
-    h = layers.apply_norm(cfg.norm, x, p, "ln_mlp")
-    if cfg.family == "moe":
+    h = layers.apply_norm(cfg.norm, x, p, "ln_mlp", cfg.norm_eps)
+    if ffn is not None:
+        m, aux = ffn(h)
+    elif dropless(cfg):
+        m, aux = moe_lib.dropless_moe(p["moe"], h, cfg, valid)
+    elif cfg.family == "moe":
         m, aux = moe_lib.moe_mlp(p["moe"], h, cfg, rules)
     else:
         m = layers.swiglu(h, p["mlp"]["w_gate"], p["mlp"]["w_up"],
@@ -532,18 +601,21 @@ def forward_features(params, cfg, tokens, *, rules=None, prefix_embeds=None
     else:
         aux0 = ({"moe_load_balance": jnp.float32(0.0),
                  "moe_z_loss": jnp.float32(0.0)}
-                if cfg.family == "moe" else {})
+                if cfg.family == "moe" and not dropless(cfg)
+                else zero_counts(cfg))
 
         def block_fn(lp, xx, i):
             x2, _, aux = attn_block(lp, xx, cfg, rules, positions=positions,
-                                    mode="full")
+                                    mode="full",
+                                    ffn=layer_ffn(params, cfg, i))
             if adaptive.mod_on(cfg):
                 # router weight scales the kept delta -> differentiable
                 x2 = adaptive.mod_apply_full(lp["router"], xx, x2, i, cfg)
             return x2, aux
         x, aux = _run_layers(params["layers"], x, cfg, rules, block_fn, aux0)
 
-    return layers.apply_norm(cfg.norm, x, params, "ln_final"), aux
+    return layers.apply_norm(cfg.norm, x, params, "ln_final",
+                             cfg.norm_eps), aux
 
 
 def unembed_weight(params, cfg):

@@ -174,7 +174,7 @@ def _logits_head(params, cfg: ModelConfig, x: jax.Array, rules):
         x = layers.layer_norm(x, params["ln_final"], params["ln_final_b"])
         w = params["embed"].astype(cdt).T
     else:
-        x = layers.apply_norm(cfg.norm, x, params, "ln_final")
+        x = layers.apply_norm(cfg.norm, x, params, "ln_final", cfg.norm_eps)
         w = (params["embed"].T if cfg.tie_embeddings
              else params["unembed"]).astype(cdt)
     logits = jnp.einsum("bsd,dv->bsv", x.astype(cdt), w)
@@ -196,6 +196,28 @@ def _decode_positions(cur_len):
     return (cl - 1).astype(jnp.int32)[:, None]
 
 
+def _attn_scan(params, cfg, rules, x, node, *, view, valid=None,
+               **attn_kw):
+    """The attention-family layer scan: ``attn_block`` at every layer,
+    with the FFN ``transformer.layer_ffn`` gives it, over ``node``'s
+    views (``view``: their ``rows``/``mask``). Returns (x, the cache
+    node with the new leaves, the layers' counts summed, as
+    ``transformer.zero_counts`` names them: ``{}`` for most models)."""
+    def f(carry, xs):
+        xx, cnt = carry
+        lp, lv, i = xs
+        xx, new_view, c = transformer.attn_block(
+            lp, xx, cfg, rules, kv_cache=node.view(lv, **view), valid=valid,
+            ffn=transformer.layer_ffn(params, cfg, i, valid), **attn_kw)
+        return (xx, {k: cnt[k] + c[k] for k in cnt}), new_view.leaves
+
+    idx = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+    (x, counts), new_leaves = jax.lax.scan(
+        f, (x, transformer.zero_counts(cfg)),
+        (params["layers"], node.layers, idx))
+    return x, node.with_layers(new_leaves), counts
+
+
 def _decode_attn_families(params, cfg, rules, x, cache, cur_len,
                           write_mask=None, live=None):
     positions = _decode_positions(cur_len)
@@ -206,6 +228,14 @@ def _decode_attn_families(params, cfg, rules, x, cache, cur_len,
     node = cache["attn"].ensure_private(
         start=jnp.asarray(cur_len, jnp.int32) - 1, width=1,
         mask=write_mask)
+    if transformer.dropless(cfg):
+        # dropless MoE: rows that do not emit are not real tokens
+        x, node, counts = _attn_scan(
+            params, cfg, rules, x, node, view={"mask": write_mask},
+            valid=None if write_mask is None else write_mask[:, None],
+            positions=positions, mode="decode", cur_len=cur_len)
+        depth = jnp.full((x.shape[0],), cfg.n_layers, jnp.int32)
+        return x, {"attn": node}, depth, counts
 
     def block_fn(lp, lv, xx, i):
         x2, new_view, _ = transformer.attn_block(
@@ -233,7 +263,7 @@ def _decode_attn_families(params, cfg, rules, x, cache, cur_len,
     x, new_leaves, depth = transformer.decode_layers(
         params["layers"], x, node.layers, cfg, block_fn=block_fn,
         halt_fn=halt_fn, kv_fill_fn=kv_fill_fn, live=live)
-    return x, {"attn": node.with_layers(new_leaves)}, depth
+    return x, {"attn": node.with_layers(new_leaves)}, depth, {}
 
 
 def _decode_ssm(params, cfg, rules, x, cache, cur_len):
@@ -299,7 +329,7 @@ def _decode_audio(params, cfg, rules, x, cache, cur_len):
 
 def decode_step(params, cfg: ModelConfig, token: jax.Array, cache: Any,
                 cur_len, rules=None, *, write_mask=None, live=None,
-                with_depth: bool = False):
+                with_depth: bool = False, with_counts: bool = False):
     """One new token against a cache of `cur_len - 1` previous positions.
 
     token: (B, 1) int32. ``cur_len`` is a scalar (whole batch at the
@@ -322,21 +352,29 @@ def decode_step(params, cfg: ModelConfig, token: jax.Array, cache: Any,
     whose halt bit should keep the dynamic layer loop alive. Retired /
     mid-prefill slots pass False: they start halted, pay no block
     FLOPs, and never extend the loop. None = every row live.
+
+    ``with_counts=True`` appends the step's layer counts
+    (``transformer.zero_counts``: a dropless MoE's routed and local
+    entries of the emitting rows, summed over layers; ``{}`` else).
     """
     cdt = cfg.dtype("compute")
     x = jnp.take(params["embed"].astype(cdt), token, axis=0)
     x = sh.constrain(x, rules, (sh.BATCH, None, None))
     fam = cfg.family
+    counts = {}
     if write_mask is not None and fam not in ("dense", "moe", "vlm"):
         raise ValueError(f"write_mask is only supported for attention "
                          f"families; got family {fam!r}")
     if adaptive.enabled(cfg):
+        if cfg.mla is not None or cfg.first_k_dense:
+            raise ValueError("adaptive depth does not serve latent "
+                             "attention or split FFN stacks")
         adaptive.validate(cfg)
     elif live is not None:
         raise ValueError("live= requires adaptive depth "
                          "(cfg.early_exit / cfg.mod_capacity)")
     if fam in ("dense", "moe", "vlm"):
-        x, new_cache, depth = _decode_attn_families(
+        x, new_cache, depth, counts = _decode_attn_families(
             params, cfg, rules, x, cache, cur_len, write_mask, live=live)
     elif fam == "ssm":
         x, new_cache = _decode_ssm(params, cfg, rules, x, cache, cur_len)
@@ -353,9 +391,12 @@ def decode_step(params, cfg: ModelConfig, token: jax.Array, cache: Any,
         raise ValueError(fam)
 
     logits = _logits_head(params, cfg, x, rules)
+    out = (logits, new_cache)
     if with_depth:
-        return logits, new_cache, depth
-    return logits, new_cache
+        out += (depth,)
+    if with_counts:
+        out += (counts,)
+    return out
 
 
 def verify_step(params, cfg: ModelConfig, tokens: jax.Array, cache: Any,
@@ -378,9 +419,10 @@ def verify_step(params, cfg: ModelConfig, tokens: jax.Array, cache: Any,
     rows exactly as in ``decode_step``.
     """
     fam = cfg.family
-    if fam not in ("dense", "moe", "vlm"):
+    if fam not in ("dense", "moe", "vlm") or cfg.mla is not None:
         raise ValueError(f"verify_step requires an attention-decoder "
-                         f"family (dense/moe/vlm); got {fam!r}")
+                         f"family (dense/moe/vlm) without latent "
+                         f"attention; got {cfg.name!r}")
     cdt = cfg.dtype("compute")
     W = tokens.shape[1]
     off = jnp.asarray(cur_len, jnp.int32) - 1                   # (B,)
@@ -435,16 +477,10 @@ def prefill(params, cfg: ModelConfig, tokens: jax.Array, cache: Any,
         # CoW before the prompt write sweep (no-op unless shared)
         node = cache["attn"].ensure_private(rows, start=0, width=S,
                                             mask=mask)
-
-        def f(carry, xs):
-            x = carry
-            lp, leaves = xs
-            x, new_view, _ = transformer.attn_block(
-                lp, x, cfg, rules, positions=positions, mode="prefill",
-                kv_cache=node.view(leaves, rows=rows, mask=mask))
-            return x, new_view.leaves
-        x, new_leaves = jax.lax.scan(f, x, (params["layers"], node.layers))
-        new_cache = {"attn": node.with_layers(new_leaves)}
+        x, node, _ = _attn_scan(params, cfg, rules, x, node,
+                                view={"rows": rows, "mask": mask},
+                                positions=positions, mode="prefill")
+        new_cache = {"attn": node}
     elif fam == "ssm":
         def f(carry, lp):
             x = carry
@@ -506,7 +542,8 @@ def prefill(params, cfg: ModelConfig, tokens: jax.Array, cache: Any,
 
 def prefill_chunk(params, cfg: ModelConfig, prompts: jax.Array, cache: Any,
                   offsets, rules=None, *, chunk: int, mask=None,
-                  prefix_embeds=None) -> Tuple[jax.Array, Any]:
+                  prefix_embeds=None, valid=None,
+                  with_counts: bool = False):
     """Advance prefill by one ``chunk``-token slice of the prompt stream.
 
     The chunked-prefill step (DESIGN.md §8.2): instead of priming the
@@ -532,6 +569,11 @@ def prefill_chunk(params, cfg: ModelConfig, prompts: jax.Array, cache: Any,
     Returns (logits (n, chunk, Vp), new_cache): each row's token-0
     sample reads ``logits[i, plen - 1 - offsets[i]]`` from the call
     whose window contains its last real position.
+
+    ``valid`` (n, chunk) bool marks the real prompt positions (default:
+    every position of a masked row): a dropless MoE layer computes
+    only their entries. ``with_counts=True`` appends the layer counts,
+    as ``decode_step``'s.
     """
     cdt = cfg.dtype("compute")
     fam = cfg.family
@@ -559,6 +601,7 @@ def prefill_chunk(params, cfg: ModelConfig, prompts: jax.Array, cache: Any,
         x = x + layers.sinusoid_at(pos, cfg.d_model, cdt)
     x = sh.constrain(x, rules, (sh.BATCH, None, None))
 
+    counts = {}
     if fam in ("dense", "moe", "vlm"):
         # CoW before the chunk write: a prefix-cache row's first
         # uncached chunk must not scribble over a shared block (the
@@ -566,16 +609,12 @@ def prefill_chunk(params, cfg: ModelConfig, prompts: jax.Array, cache: Any,
         # practice; it is the safety invariant)
         node = cache["attn"].ensure_private(start=offsets, width=C,
                                             mask=mask)
-
-        def f(carry, xs):
-            x = carry
-            lp, leaves = xs
-            x, new_view, _ = transformer.attn_block(
-                lp, x, cfg, rules, positions=pos, mode="chunk",
-                kv_cache=node.view(leaves, mask=mask), chunk_off=offsets)
-            return x, new_view.leaves
-        x, new_leaves = jax.lax.scan(f, x, (params["layers"], node.layers))
-        new_cache = {"attn": node.with_layers(new_leaves)}
+        if valid is None and mask is not None:
+            valid = jnp.broadcast_to(jnp.asarray(mask)[:, None], (n, C))
+        x, node, counts = _attn_scan(
+            params, cfg, rules, x, node, view={"mask": mask}, valid=valid,
+            positions=pos, mode="chunk", chunk_off=offsets)
+        new_cache = {"attn": node}
     else:   # audio: cross cache must already be primed (written once
             # per request at its fixed n_frames width)
         node = cache["self"].ensure_private(start=offsets, width=C,
@@ -595,7 +634,10 @@ def prefill_chunk(params, cfg: ModelConfig, prompts: jax.Array, cache: Any,
         new_cache = {"self": node.with_layers(new_leaves),
                      "cross": cache["cross"]}
 
-    return _logits_head(params, cfg, x, rules), new_cache
+    logits = _logits_head(params, cfg, x, rules)
+    if with_counts:
+        return logits, new_cache, counts
+    return logits, new_cache
 
 
 # =========================== in-graph generation ============================

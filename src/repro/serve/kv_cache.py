@@ -50,6 +50,12 @@ Layout invariants:
   ``n_blocks`` and scatter with ``mode="drop"`` — a retired row whose
   table was freed appends nowhere instead of corrupting a recycled
   block.
+- **Latent layout** (latent attention, ``cfg.mla``): a paged pool
+  whose ``k_pool`` holds one row per token, ``c ‖ k_pe`` for all heads
+  at once as a single head ``(L, n_blocks, 1, block, row_lanes)``,
+  and no V pool (``v_pool is None``; every V argument and result is
+  None). Block tables, ``alloc``/``free``, copy-on-write and
+  block shipping are the same code.
 """
 
 from __future__ import annotations
@@ -242,6 +248,8 @@ class PagedView:
     @property
     def leaves(self):
         # The table is NOT a per-layer leaf: appends never change it.
+        if self.v_pool is None:
+            return {"k": self.k_pool}
         return {"k": self.k_pool, "v": self.v_pool}
 
     @property
@@ -289,7 +297,7 @@ class PagedView:
         kp = self.k_pool.at[fb, :, fo].set(
             k.astype(self.k_pool.dtype).reshape((n * C,) + k.shape[2:]),
             mode="drop")
-        vp = self.v_pool.at[fb, :, fo].set(
+        vp = None if self.v_pool is None else self.v_pool.at[fb, :, fo].set(
             v.astype(self.v_pool.dtype).reshape((n * C,) + v.shape[2:]),
             mode="drop")
         return dataclasses.replace(self, k_pool=kp, v_pool=vp)
@@ -305,8 +313,8 @@ class PagedView:
             blk = jnp.where(self.mask, blk, self.n_blocks)
         kp = self.k_pool.at[blk, :, off].set(
             k[:, 0].astype(self.k_pool.dtype), mode="drop")
-        vp = self.v_pool.at[blk, :, off].set(
-            v[:, 0].astype(self.v_pool.dtype), mode="drop")
+        vp = None if self.v_pool is None else self.v_pool.at[
+            blk, :, off].set(v[:, 0].astype(self.v_pool.dtype), mode="drop")
         return dataclasses.replace(self, k_pool=kp, v_pool=vp)
 
     def gather(self) -> Tuple[jax.Array, jax.Array]:
@@ -341,7 +349,8 @@ class PagedView:
             return g.reshape((n, bpr * self.block) + g.shape[3:])
 
         return (dense(self.k_pool)[:, :self.max_len],
-                dense(self.v_pool)[:, :self.max_len])
+                None if self.v_pool is None
+                else dense(self.v_pool)[:, :self.max_len])
 
     def paged_state(self):
         """Gather-free kernel operands ``(k_pool, v_pool, table)`` —
@@ -539,7 +548,7 @@ class PagedKVCache(KVCache):
     """
 
     k_pool: jax.Array        # (L, n_blocks, KV, block, hd)
-    v_pool: jax.Array
+    v_pool: Optional[jax.Array]  # None: the latent layout
     table: jax.Array         # (n_rows, blocks_per_row) int32, -1 = unalloc
     owner: jax.Array         # (n_blocks,) int32, -1 = free
     refcount: jax.Array = None   # (n_blocks,) int32, 0 = free
@@ -556,22 +565,23 @@ class PagedKVCache(KVCache):
     @classmethod
     def create(cls, n_layers: int, n_rows: int, max_len: int, kv_heads: int,
                head_dim: int, dtype, *, block: int = 16,
-               n_blocks: Optional[int] = None, abstract: bool = False
-               ) -> "PagedKVCache":
+               n_blocks: Optional[int] = None, abstract: bool = False,
+               latent: bool = False) -> "PagedKVCache":
         """``n_blocks`` defaults to dense-equivalent capacity
         (``n_rows * ceil(max_len / block)``); serving pools pass less —
-        that under-provisioning is the whole point."""
+        that under-provisioning is the whole point. ``latent``: no V
+        pool (the latent layout)."""
         bpr = math.ceil(max_len / block)
         nb = n_rows * bpr if n_blocks is None else int(n_blocks)
         pshape = (n_layers, nb, kv_heads, block, head_dim)
         if abstract:
             e = jax.ShapeDtypeStruct(pshape, dtype)
             vec = jax.ShapeDtypeStruct((nb,), jnp.int32)
-            return cls(k_pool=e, v_pool=e,
+            return cls(k_pool=e, v_pool=None if latent else e,
                        table=jax.ShapeDtypeStruct((n_rows, bpr), jnp.int32),
                        owner=vec, refcount=vec, max_len=max_len)
         return cls(k_pool=jnp.zeros(pshape, dtype),
-                   v_pool=jnp.zeros(pshape, dtype),
+                   v_pool=None if latent else jnp.zeros(pshape, dtype),
                    table=jnp.full((n_rows, bpr), -1, jnp.int32),
                    owner=jnp.full((nb,), -1, jnp.int32),
                    refcount=jnp.zeros((nb,), jnp.int32),
@@ -610,15 +620,17 @@ class PagedKVCache(KVCache):
 
     @property
     def layers(self):
+        if self.v_pool is None:
+            return {"k": self.k_pool}
         return {"k": self.k_pool, "v": self.v_pool}
 
     def view(self, leaves, rows=None, mask=None) -> PagedView:
-        return PagedView(leaves["k"], leaves["v"], self.table,
+        return PagedView(leaves["k"], leaves.get("v"), self.table,
                          self.max_len, rows=rows, mask=mask)
 
     def with_layers(self, stacked) -> "PagedKVCache":
         return dataclasses.replace(self, k_pool=stacked["k"],
-                                   v_pool=stacked["v"])
+                                   v_pool=stacked.get("v"))
 
     # ---- lifecycle (pure array ops; run inside jit / while bodies) ----
 
@@ -799,8 +811,8 @@ class PagedKVCache(KVCache):
             old_safe = jnp.clip(old, 0, cache.n_blocks - 1)
             k_pool = cache.k_pool.at[:, fresh].set(
                 cache.k_pool[:, old_safe], mode="drop")
-            v_pool = cache.v_pool.at[:, fresh].set(
-                cache.v_pool[:, old_safe], mode="drop")
+            v_pool = None if cache.v_pool is None else cache.v_pool.at[
+                :, fresh].set(cache.v_pool[:, old_safe], mode="drop")
             # repoint the row's entry (fresh copy, or -1 when the pool
             # is dry); the old block loses this row's reference either
             # way
@@ -840,7 +852,8 @@ class PagedKVCache(KVCache):
         live = (cols >= 0)[None, :, :, None, None, None]
         safe = jnp.clip(cols, 0)
         k = jnp.where(live, self.k_pool[:, safe], 0)
-        v = jnp.where(live, self.v_pool[:, safe], 0)
+        v = (None if self.v_pool is None
+             else jnp.where(live, self.v_pool[:, safe], 0))
         return k, v
 
     def import_rows(self, rows, k_data, v_data,
@@ -860,10 +873,12 @@ class PagedKVCache(KVCache):
         flat = dst.reshape(-1)
         kd = k_data.astype(self.k_pool.dtype).reshape(
             (k_data.shape[0], n * n_cols) + k_data.shape[3:])
-        vd = v_data.astype(self.v_pool.dtype).reshape(
-            (v_data.shape[0], n * n_cols) + v_data.shape[3:])
         k_pool = self.k_pool.at[:, flat].set(kd, mode="drop")
-        v_pool = self.v_pool.at[:, flat].set(vd, mode="drop")
+        v_pool = None
+        if self.v_pool is not None:
+            vd = v_data.astype(self.v_pool.dtype).reshape(
+                (v_data.shape[0], n * n_cols) + v_data.shape[3:])
+            v_pool = self.v_pool.at[:, flat].set(vd, mode="drop")
         return dataclasses.replace(self, k_pool=k_pool, v_pool=v_pool)
 
     def shardings(self, rules, mesh=None, row_axis: str = sh.BATCH):
@@ -873,7 +888,7 @@ class PagedKVCache(KVCache):
         vec = rules.sharding((sh.BLOCK,), mesh,
                              dims=tuple(self.owner.shape))
         return PagedKVCache(
-            k_pool=pool, v_pool=pool,
+            k_pool=pool, v_pool=None if self.v_pool is None else pool,
             table=rules.sharding((row_axis, None), mesh,
                                  dims=tuple(self.table.shape)),
             owner=vec, refcount=vec,
@@ -884,9 +899,19 @@ def make_kv_cache(cfg, n_layers: int, n_rows: int, max_len: int, *,
                   impl: str = "dense", block: int = 16,
                   n_blocks: Optional[int] = None,
                   abstract: bool = False) -> KVCache:
-    """Build a self-attention KV cache for ``cfg``'s head geometry."""
+    """Build a self-attention KV cache for ``cfg``'s head geometry (the
+    latent layout for latent attention, which only a paged pool
+    serves)."""
     KV, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     dt = cfg.dtype("compute")
+    if getattr(cfg, "mla", None) is not None:
+        if impl != "paged":
+            raise ValueError("latent attention is served from a paged "
+                             "pool (kv='paged')")
+        return PagedKVCache.create(n_layers, n_rows, max_len, 1,
+                                   cfg.mla.row_lanes, dt, block=block,
+                                   n_blocks=n_blocks, abstract=abstract,
+                                   latent=True)
     if impl == "dense":
         return DenseKVCache.create(n_layers, n_rows, max_len, KV, hd, dt,
                                    abstract=abstract)

@@ -71,7 +71,9 @@ key). Three layers:
    ``cur_len`` ever exposes it. SSM/hybrid prefills keep updating
    their recurrent state through a pad tail and MoE capacity routing
    lets pads displace real tokens, so those families require
-   exact-length prompts (``submit`` rejects anything else).
+   exact-length prompts (``submit`` rejects anything else). A dropless
+   MoE layer (``cfg.moe.dispatch == "dropless"``) has no capacity, so
+   pads displace nothing and its prompts bucket like dense ones.
 
 3. **Host driver** (``DecodeScheduler``): keeps a FIFO queue, admits
    between device segments, harvests finished requests. Admission
@@ -115,6 +117,7 @@ from .. import core
 from ..dist import sharding as sh
 from . import engine, kv_cache as kvc, sampling as sampling_lib
 from . import speculative as spec_lib
+from ..models import transformer
 
 
 # "no per-segment iteration cap": large enough that the free-slot
@@ -187,6 +190,10 @@ class SlotPool:
                              # ceil(cur_len / kv_block) at those
                              # iterations: the pages the decode kernel
                              # reads per layer (0 for a dense cache)
+    moe_routed: Any = None   # scalar int32 — Σ over MoE layers of the
+                             # routed entries of real tokens (dropless
+                             # MoE pools; else None; monotonic)
+    moe_local: Any = None    # scalar int32 — those on experts held here
 
     def tree_flatten(self):
         return (self.cache, self.next_token, self.cur_len, self.n_emitted,
@@ -196,7 +203,8 @@ class SlotPool:
                 self.prefix, self.draft, self.slot_accepted,
                 self.slot_windows, self.priority, self.deadline,
                 self.slot_layers, self.slot_decodes, self.chunk_iters,
-                self.chunk_rows, self.decode_iters, self.decode_pages), None
+                self.chunk_rows, self.decode_iters, self.decode_pages,
+                self.moe_routed, self.moe_local), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -408,7 +416,17 @@ def pool_shardings(cfg, n_slots: int, max_len: int, max_new_cap: int,
         slot_accepted=vec, slot_windows=vec,
         priority=vec, deadline=vec,
         slot_layers=vec, slot_decodes=vec,
-        chunk_iters=rep, chunk_rows=rep, decode_iters=rep, decode_pages=rep)
+        chunk_iters=rep, chunk_rows=rep, decode_iters=rep, decode_pages=rep,
+        **{k: rep for k in transformer.zero_counts(cfg)})
+
+
+def _add_counts(p: "SlotPool", counts: dict) -> "SlotPool":
+    """Fold a step's layer counts (``engine.decode_step``'s
+    ``with_counts``) into the pool's lifetime counters of those names."""
+    if not counts:
+        return p
+    return dataclasses.replace(p, **{k: getattr(p, k) + v
+                                     for k, v in counts.items()})
 
 
 def _under_mesh(fn, mesh):
@@ -606,11 +624,13 @@ class DecodeScheduler:
         # exact-length prompts (one prefill shape, as before). Chunked
         # mode keeps the same per-family rule: a ragged final chunk
         # puts its garbage tail inside the row's own routing group, so
-        # MoE stays exact-length there too.
+        # capacity MoE stays exact-length there too. A dropless MoE
+        # layer has no capacity for pads to take: no such rule.
         if prefill == "chunked":
             self._bucketed = cfg.family in ("dense", "vlm")
         else:
             self._bucketed = cfg.family in ("dense", "vlm", "audio")
+        self._bucketed |= transformer.dropless(cfg)
         self._base_key = jax.random.PRNGKey(seed)
         self._next_rid = 0
         self.queue: List[_Queued] = []
@@ -708,7 +728,8 @@ class DecodeScheduler:
             chunk_iters=jnp.asarray(0, jnp.int32),
             chunk_rows=jnp.asarray(0, jnp.int32),
             decode_iters=jnp.asarray(0, jnp.int32),
-            decode_pages=jnp.asarray(0, jnp.int32))
+            decode_pages=jnp.asarray(0, jnp.int32),
+            **transformer.zero_counts(self.cfg))
         if self.rules is not None and self.mesh is not None \
                 and self.mesh.size > 1:
             shd = pool_shardings(self.cfg, n, self.max_len, cap,
@@ -1044,9 +1065,15 @@ class DecodeScheduler:
             (``harvest_prefilled``), and the decode branch of the loop
             never fires.
             """
-            logits, cache = engine.prefill_chunk(
+            # real prompt positions: the rest of a ragged last chunk,
+            # and rows not prefilling, are computed and discarded
+            pos = p.pf_pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
+            valid = p.prefilling[:, None] & (pos < p.plen[:, None])
+            logits, cache, counts = engine.prefill_chunk(
                 params, cfg, p.prompt, p.cache, p.pf_pos, rules,
-                chunk=C, mask=p.prefilling, prefix_embeds=p.prefix)
+                chunk=C, mask=p.prefilling, prefix_embeds=p.prefix,
+                valid=valid, with_counts=True)
+            p = _add_counts(p, counts)
             draft = p.draft
             if d_cfg is not None:
                 # The draft model prefills the same prompt stream into
@@ -1103,10 +1130,12 @@ class DecodeScheduler:
             # halted and pay no block FLOPs). `depth` feeds the
             # per-slot mean-depth stats either way (== n_layers for
             # static-depth pools).
-            logits, cache, depth = engine.decode_step(
+            logits, cache, depth, counts = engine.decode_step(
                 params, cfg, tok[:, None], cache, p.cur_len, rules,
                 write_mask=emit if chunked else None,
-                live=emit if cfg.early_exit else None, with_depth=True)
+                live=emit if cfg.early_exit else None, with_depth=True,
+                with_counts=True)
+            p = _add_counts(p, counts)
             keys = sampling_lib.step_keys(p.keys, n_emitted)
             nxt = sampling_lib.sample_slots(logits[:, 0], keys, sp)
             return dataclasses.replace(
@@ -2095,11 +2124,16 @@ class DecodeScheduler:
         iterations that ran the decode branch) and ``decode_pages`` (Σ
         over the ``n_slots`` rows of ``ceil(cur_len / kv_block)`` at
         those iterations: the pages the decode kernel reads per layer;
-        0 for a dense cache). Read from the device in one transfer, so
-        only when asked. They are int32 in-graph: take differences
-        modulo 2**32."""
-        names = ("chunk_iters", "chunk_rows", "decode_iters",
-                 "decode_pages")
+        0 for a dense cache); a dropless MoE pool adds ``moe_routed``
+        (Σ over MoE layers of the routed entries of real tokens: prompt
+        positions of prefilling rows, emitting rows in decode) and
+        ``moe_local`` (those on the experts held here). Read from the
+        device in one transfer, so only when asked. They are int32
+        in-graph: take differences modulo 2**32."""
+        names = tuple(k for k in ("chunk_iters", "chunk_rows",
+                                  "decode_iters", "decode_pages",
+                                  "moe_routed", "moe_local")
+                      if getattr(self.pool, k) is not None)
         vals = jax.device_get(tuple(getattr(self.pool, k) for k in names))
         return {k: int(v) for k, v in zip(names, vals)}
 

@@ -423,6 +423,49 @@ class TestFlashPrefill:
             rtol=0, atol=0)
 
 
+class TestLatentPool:
+    """Latent attention's cache read: one shared head of rows that are
+    their own value (``v_pool=None``), each page fetched once; the
+    result equals the kernel handed the same pool as K and V, at a
+    scale other than ``1/sqrt(hd)``."""
+
+    @pytest.mark.parametrize("kernel", ["decode", "chunk"])
+    def test_latent_pool_is_its_own_value(self, kernel):
+        B, H, hd, block, bpr = 3, 4, 40, 4, 5
+        if kernel == "decode":
+            q, kp, _, table, lens = _paged_case(B, H, 1, hd, block, bpr,
+                                                jnp.float32)
+            fn = paged_attention
+        else:
+            q, kp, _, table, lens = _prefill_case(B, 5, H, 1, hd, block,
+                                                  bpr, jnp.float32)
+            fn = flash_prefill
+        got = fn(q, kp, None, table, lens, scale=0.3)
+        want = fn(q, kp, kp, table, lens, scale=0.3)
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+        assert float(jnp.abs(got - fn(q, kp, kp, table, lens)).max()) \
+            > 1e-3
+
+
+class TestGroupedMatmul:
+    """The megablox grouped matmul over the experts held here against
+    ``lax.ragged_dot``: groups of 0 and of more than a row tile, and
+    rows past the groups' total, which come back as zeros."""
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_matches_ragged_dot(self, dtype):
+        from repro.kernels.grouped_matmul.ops import TM, expert_gmm, \
+            expert_gmm_ref
+        sizes = jnp.asarray([5, 0, 130, 1], jnp.int32)
+        M, K, N = 2 * TM, 128, 256
+        lhs, rhs = rand((M, K), dtype, 0), rand((4, K, N), dtype, 1)
+        got = expert_gmm(lhs, rhs, sizes).astype(jnp.float32)
+        want = expert_gmm_ref(lhs, rhs, sizes).astype(jnp.float32)
+        tol = 5e-2 if dtype == jnp.bfloat16 else 1e-4
+        np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+        assert not got[136:].any() and got[135].any()
+
+
 class TestSelectiveScan:
     @pytest.mark.parametrize("B,Q,Di,N,blk", [
         (1, 16, 32, 8, 32),

@@ -67,8 +67,9 @@ def tpu_ops(monkeypatch):
     interpret-mode) form: ``on_tpu()`` reads the CPU backend here."""
     from repro.kernels.flash_attention import ops as fa
     from repro.kernels.flash_prefill import ops as fp
+    from repro.kernels.grouped_matmul import ops as gm
     from repro.kernels.paged_attention import ops as pa
-    for mod in (fa, fp, pa):
+    for mod in (fa, fp, pa, gm):
         monkeypatch.setattr(mod, "on_tpu", lambda: True)
     return fa, fp, pa
 
@@ -216,3 +217,58 @@ def test_sliced_pool_step_compiles(topo, no_compile_cache, tpu_ops):
         text = jax.jit(sched._build_step()).lower(
             params, None, pool, scalar, scalar).compile().as_text()
     assert text.count("tpu_custom_call") >= 2   # decode + chunk kernels
+
+
+# moonlight-16b-a3b-ep8pp2.doc_batch: 16 heads of 640 lanes (the 576
+# values of a latent row, padded to whole lane tiles) over one shared
+# latent head, 128 rows, 193 blocks per row, the mix's 7168 blocks
+LATENT = dict(H=16, lanes=640, rows=128, bpr=193, n_blocks=7168, chunk=64)
+
+
+def _custom_calls(text):
+    return [line.split("=")[0].split()[-1] for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+@pytest.mark.parametrize("mode", ["decode", "chunk"])
+def test_latent_pool_kernels_compile(mode, one_chip, no_compile_cache,
+                                     tpu_ops):
+    """Latent attention's cache reads at the cell's shapes: the decode
+    and chunk-prefill kernels with the latent pool as their one K head
+    and no V pool, under the names the trace reduction reads."""
+    _, fp, pa = tpu_ops
+    a = LATENT
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    pool = s((a["n_blocks"], 1, BLOCK, a["lanes"]), jnp.bfloat16)
+    table, rows = s((a["rows"], a["bpr"]), jnp.int32), s((a["rows"],),
+                                                          jnp.int32)
+    if mode == "decode":
+        fn = functools.partial(pa.paged_attention, scale=192 ** -0.5)
+        q, name = s((a["rows"], 1, a["H"], a["lanes"]), jnp.bfloat16), \
+            "%paged_attention"
+    else:
+        fn = functools.partial(fp.flash_prefill, scale=192 ** -0.5)
+        q, name = s((a["rows"], a["chunk"], a["H"], a["lanes"]),
+                    jnp.bfloat16), "%flash_prefill"
+    calls = _custom_calls(_compiled_text(
+        lambda q, k, t, r: fn(q, k, None, t, r), q, pool, table, rows))
+    assert len(calls) == 1 and calls[0].startswith(name), calls
+
+
+# (entries, in, out): a decode step's 128 x 6 entries and a chunk
+# iteration's 128 x 64 x 6, through gate/up (2048 -> 1408) and down
+GMM = {"decode_up": (768, 2048, 1408), "chunk_up": (49152, 2048, 1408),
+       "chunk_down": (49152, 1408, 2048)}
+
+
+@pytest.mark.parametrize("case", sorted(GMM))
+def test_grouped_matmul_compiles(case, one_chip, no_compile_cache, tpu_ops):
+    """The megablox grouped matmul over the 8 experts held here, at the
+    cell's entry counts, as the ``gmm`` custom call."""
+    from repro.kernels.grouped_matmul import ops as gm
+    M, K, N = GMM[case]
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    calls = _custom_calls(_compiled_text(
+        gm.expert_gmm, s((M, K), jnp.bfloat16), s((8, K, N), jnp.bfloat16),
+        s((8,), jnp.int32)))
+    assert calls and all(c.startswith("%gmm") for c in calls), calls
